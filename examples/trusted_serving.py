@@ -1,8 +1,7 @@
 """Serving example: batched greedy generation through the serving engine,
 a verified (commit-challenge-audit) serving session that finalizes only
 audited outputs, plus the LM-scale trusted-MoE consensus demonstrated on
-a multi-device mesh (subprocess with virtual devices, since this
-container has 1 CPU).
+a multi-device mesh (a subprocess on 8 virtual CPU devices).
 
 Run:  PYTHONPATH=src python examples/trusted_serving.py
 """
@@ -65,8 +64,9 @@ print("\n=== B-MoE consensus at LM scale (r=4 replicas, 1 malicious) ===")
 code = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.trusted_moe import make_trust, LMAttack
+from repro.launch.mesh import make_mesh
 from repro.models.config import RedundancyConfig
-mesh = jax.make_mesh((1, 4, 2), ("data", "replica", "model"))
+mesh = make_mesh((1, 4, 2), ("data", "replica", "model"))
 y = jax.random.normal(jax.random.PRNGKey(0), (4, 16, 8, 32))  # (B,E,C,d)
 for mode in ("faithful", "digest"):
     trust = make_trust(mesh, RedundancyConfig(4, mode), True,
@@ -76,7 +76,12 @@ for mode in ("faithful", "digest"):
     ok = np.allclose(np.asarray(out), np.asarray(y), atol=1e-6)
     print(f"  mode={mode}: attack repaired by consensus -> {ok}")
 """
+# the child runs on virtual CPU devices, pinned with JAX_PLATFORMS=cpu:
+# this process already holds the accelerator (sections 1-2), and a child
+# that asked for it too would fail or hang.  On a TPU host the replica
+# mesh vote runs on real chips through ``chip_smoke.py --chips 4``.
 env = dict(os.environ)
+env["JAX_PLATFORMS"] = "cpu"
 env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src") \
     + os.pathsep + env.get("PYTHONPATH", "")

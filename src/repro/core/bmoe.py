@@ -50,7 +50,6 @@ from repro.storage import (ExpertCache, ExpertStore, GateEMA,
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.models.moe import capacity_positions
-from repro.models.moe_ep import _shard_map
 from repro.trust.audit import pack_audit_batch, pack_audit_batch_multi
 from repro.trust.commitments import chunk_bounds
 from repro.trust.da import DataAvailabilityAuditor
@@ -831,15 +830,15 @@ class BMoESystem:
                 work = [(e, sl) for e in range(e_l) for sl in slices]
                 parts = []
                 for s in range(self.mesh_shards):
-                    bank_s = jax.tree_util.tree_map(
-                        lambda a: a[s * e_l:(s + 1) * e_l], experts)
+                    bank_s, xd_s = self._on_shard(s, jax.tree_util.tree_map(
+                        lambda a: a[s * e_l:(s + 1) * e_l], experts), xd)
                     rmap = (None if row_index is None
                             else row_index[s * e_l:(s + 1) * e_l])
                     idx, gid, n = pack_audit_batch(
                         [e for e, _ in work], [sl for _, sl in work],
                         row_map=rmap)
                     out = np.asarray(self._batched_recompute_call(
-                        bank_s, xd, jnp.asarray(idx),
+                        bank_s, xd_s, jnp.asarray(idx),
                         jnp.asarray(gid)))[:n]
                     parts.extend(np.concatenate(
                         [out[e * n_chunks + c][:bounds[c + 1] - bounds[c]]
@@ -949,6 +948,14 @@ class BMoESystem:
 
         return batch_recompute
 
+    def _on_shard(self, s: int, *trees):
+        """Commit ``trees`` to the device of edge shard ``s``.  A Pallas
+        kernel cannot be partitioned over the mesh, so each shard's
+        recompute runs as a one-device program on the device that holds
+        its expert slice."""
+        dev = self.device_mesh.devices.reshape(-1, self.mesh_shards)[0, s]
+        return jax.device_put(trees, dev)
+
     def _shard_groups(self, expert_ids):
         """Sample indices grouped by the edge shard owning each sampled
         expert — mesh execution routes every audit recompute to the
@@ -979,15 +986,15 @@ class BMoESystem:
         cmax = max(sl.stop - sl.start for sl in slices)
         out = None
         for s, sel in sorted(groups.items()):
-            bank_s = jax.tree_util.tree_map(
-                lambda a: a[s * e_l:(s + 1) * e_l], experts)
+            bank_s, xd_s = self._on_shard(s, jax.tree_util.tree_map(
+                lambda a: a[s * e_l:(s + 1) * e_l], experts), xd)
             rmap = (None if row_index is None
                     else row_index[s * e_l:(s + 1) * e_l])
             idx, gid, n = pack_audit_batch(
                 [int(expert_ids[i]) - s * e_l for i in sel],
                 [slices[i] for i in sel], row_map=rmap)
             part = np.asarray(self._batched_recompute_call(
-                bank_s, xd, jnp.asarray(idx), jnp.asarray(gid)))[:n]
+                bank_s, xd_s, jnp.asarray(idx), jnp.asarray(gid)))[:n]
             if out is None:
                 out = np.zeros((len(expert_ids), cmax) + part.shape[2:],
                                part.dtype)
@@ -1119,12 +1126,12 @@ class BMoESystem:
             cmax = max(sl.stop - sl.start for sl in slices)
             out = None
             for s, sel in sorted(groups.items()):
-                bank_s = jax.tree_util.tree_map(
+                bank_s, xcat_s = self._on_shard(s, jax.tree_util.tree_map(
                     lambda a: a.reshape((slots, cfg.num_experts)
                                         + a.shape[1:])
                     [:, s * e_l:(s + 1) * e_l]
                     .reshape((slots * e_l,) + a.shape[1:]),
-                    stacked_bank)
+                    stacked_bank), xcat)
                 rmaps_s = [None if rm is None
                            else rm[s * e_l:(s + 1) * e_l]
                            for rm in row_maps]
@@ -1137,7 +1144,7 @@ class BMoESystem:
                     [slices[i] for i in sel], row_off, e_l,
                     bucket=bucket, row_maps=rmaps_s)
                 part = np.asarray(self._batched_recompute_call(
-                    bank_s, xcat, jnp.asarray(idx), jnp.asarray(gid)))[:n]
+                    bank_s, xcat_s, jnp.asarray(idx), jnp.asarray(gid)))[:n]
                 if out is None:
                     out = np.zeros((len(experts), cmax) + part.shape[2:],
                                    part.dtype)
@@ -1814,11 +1821,11 @@ def _mesh_sparse_forward(experts, xin, topi, weights, capacity, mask_e, key,
 
     rep = P()
     bank_specs = jax.tree_util.tree_map(lambda _: P("model"), experts)
-    mapped = _shard_map(
-        body, mesh,
+    mapped = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(rep, rep, rep, rep, rep, bank_specs, rep, rep, rep,
                   rep, rep, rep, rep),
-        out_specs=(P("model"), P("model"), P("model")))
+        out_specs=(P("model"), P("model"), P("model")), check_vma=False)
     act = active if active is not None else jnp.ones(cfg.num_edges)
     y, support, flags = mapped(
         xin_p, eid_p, posc_p, keep_p, wk_p, experts, slot_src, mask_e,

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels import ref as kref
+from repro.launch.mesh import make_mesh
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,24 +45,11 @@ class LMAttack:
     seed: int = 0
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (TypeError, AttributeError):  # older jax spelling
-        from jax.experimental.shard_map import shard_map
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-
-
 def _inject(y, attack: Optional[LMAttack]):
     if attack is None or not attack.malicious_replicas:
         return y
     rid = jax.lax.axis_index("replica")
-    try:
-        n_rep = jax.lax.axis_size("replica")
-    except AttributeError:                 # older jax spelling
-        n_rep = jax.lax.psum(1, "replica")
+    n_rep = jax.lax.axis_size("replica")
     mal = jnp.zeros((n_rep,), jnp.float32)
     mal = mal.at[jnp.array(attack.malicious_replicas, jnp.int32)].set(1.0)
     key = jax.random.PRNGKey(attack.seed)
@@ -120,8 +108,8 @@ def make_trust(mesh: Optional[Mesh], rcfg, expert_sharded: bool,
     batch = tuple(a for a in ("pod", "data") if a in mesh.axis_names) or None
     spec = P(batch, "model" if expert_sharded else None, None, None)
     body = _vote_faithful if rcfg.mode == "faithful" else _vote_digest
-    return _shard_map(functools.partial(body, attack=attack), mesh,
-                      in_specs=(spec,), out_specs=spec)
+    return jax.shard_map(functools.partial(body, attack=attack), mesh=mesh,
+                         in_specs=(spec,), out_specs=spec, check_vma=False)
 
 
 def make_trusted_mesh(r: int, *, data: int = 16, model: int = 16,
@@ -130,6 +118,6 @@ def make_trusted_mesh(r: int, *, data: int = 16, model: int = 16,
     if data % r:
         raise ValueError(f"redundancy r={r} must divide data={data}")
     if multi_pod:
-        return jax.make_mesh((2, data // r, r, model),
-                             ("pod", "data", "replica", "model"))
-    return jax.make_mesh((data // r, r, model), ("data", "replica", "model"))
+        return make_mesh((2, data // r, r, model),
+                         ("pod", "data", "replica", "model"))
+    return make_mesh((data // r, r, model), ("data", "replica", "model"))

@@ -72,7 +72,10 @@ def audit_mlp(params, x: jax.Array, gid: jax.Array, *, block_d: int = 256,
     xp = _pad_axis(_pad_axis(x, 1, 8), 2, block_d)
     w1p = _pad_axis(w1, 1, block_d)
     w2p = _pad_axis(w2, 2, 128)
-    b2p = _pad_axis(b2, 1, 128)
+    # biases ride as (E, 1, .) so each block's last two dims equal the
+    # array's (Mosaic tiles the last two dims by (8, 128) or full size)
+    b1r = b1[:, None, :]
+    b2r = _pad_axis(b2, 1, 128)[:, None, :]
     Cp, dp = xp.shape[1], xp.shape[2]
     h = w1.shape[-1]
     op = w2p.shape[-1]
@@ -83,9 +86,9 @@ def audit_mlp(params, x: jax.Array, gid: jax.Array, *, block_d: int = 256,
         in_specs=[
             pl.BlockSpec((1, Cp, block_d), lambda s, k, gid: (s, 0, k)),
             pl.BlockSpec((1, block_d, h), lambda s, k, gid: (gid[s], k, 0)),
-            pl.BlockSpec((1, h), lambda s, k, gid: (gid[s], 0)),
+            pl.BlockSpec((1, 1, h), lambda s, k, gid: (gid[s], 0, 0)),
             pl.BlockSpec((1, h, op), lambda s, k, gid: (gid[s], 0, 0)),
-            pl.BlockSpec((1, op), lambda s, k, gid: (gid[s], 0)),
+            pl.BlockSpec((1, 1, op), lambda s, k, gid: (gid[s], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, Cp, op), lambda s, k, gid: (s, 0, 0)),
         scratch_shapes=[pltpu.VMEM((Cp, h), jnp.float32)],
@@ -95,5 +98,5 @@ def audit_mlp(params, x: jax.Array, gid: jax.Array, *, block_d: int = 256,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, Cp, op), jnp.float32),
         interpret=interpret,
-    )(gid.astype(jnp.int32), xp, w1p, b1, w2p, b2p)
+    )(gid.astype(jnp.int32), xp, w1p, b1r, w2p, b2r)
     return out[:, :C, :o]

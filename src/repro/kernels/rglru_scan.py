@@ -32,7 +32,7 @@ def _rglru_kernel(a_ref, b_ref, y_ref, h_ref, *, Sq):
 
     def body(i, h):
         h = a[i] * h + b[i]
-        pl.store(y_ref, (0, pl.dslice(i, 1), slice(None)), h[None])
+        y_ref[0, pl.ds(i, 1), :] = h[None]
         return h
 
     h_ref[...] = jax.lax.fori_loop(0, Sq, body, h_ref[...])

@@ -13,9 +13,21 @@ model cannot shard over.
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """``jax.make_mesh`` with every axis ``Auto``-typed — the one mesh
+    constructor of the repo.  jax's own default is ``Explicit`` axes,
+    under which ``with_sharding_constraint`` rejects the specs the
+    sharding rules emit and indexing a sharded array raises; every step
+    function here is written for compiler-propagated (``Auto``)
+    shardings."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def _model_width(n: int, divides: Optional[int] = None,
@@ -35,7 +47,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     into the batch sharding (dp = pod x data)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_trusted_mesh(r: int, *, multi_pod: bool = False):
@@ -58,9 +70,9 @@ def make_trusted_mesh(r: int, *, multi_pod: bool = False):
     model = _model_width(rest, cap=16)
     data = rest // model
     if multi_pod:
-        return jax.make_mesh((2, data, r, model),
-                             ("pod", "data", "replica", "model"))
-    return jax.make_mesh((data, r, model), ("data", "replica", "model"))
+        return make_mesh((2, data, r, model),
+                         ("pod", "data", "replica", "model"))
+    return make_mesh((data, r, model), ("data", "replica", "model"))
 
 
 def make_host_mesh(num_experts: Optional[int] = None):
@@ -73,7 +85,7 @@ def make_host_mesh(num_experts: Optional[int] = None):
     parallelism raise whenever ``num_experts % n != 0``."""
     n = len(jax.devices())
     model = _model_width(n, divides=num_experts)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def make_edge_mesh(num_experts: int, *, shards: Optional[int] = None):
@@ -94,4 +106,4 @@ def make_edge_mesh(num_experts: int, *, shards: Optional[int] = None):
             f"num_experts ({num_experts}) % mesh_shards ({shards}) != 0 — "
             f"each edge shard must own a whole expert slice; pick shards "
             f"from the divisors of {num_experts}")
-    return jax.make_mesh((n // shards, shards), ("data", "model"))
+    return make_mesh((n // shards, shards), ("data", "model"))

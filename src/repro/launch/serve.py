@@ -11,6 +11,7 @@ import argparse
 
 from repro.configs import ARCH_IDS, get_config
 from repro.data.synthetic import serving_requests
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.engine import ServingEngine
 from repro.serve.scheduler import POLICIES
 from repro.train.loop import init_model
@@ -28,9 +29,13 @@ def main():
                     default="continuous")
     ap.add_argument("--prefill-chunk", type=int, default=16,
                     help="max prompt tokens fused per compiled step")
+    ap.add_argument("--full", action="store_true",
+                    help="use the full (not smoke) config at its published "
+                         "widths")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch, smoke=True)
+    enable_compile_cache()
+    cfg = get_config(args.arch, smoke=not args.full)
     if cfg.is_encoder_decoder:
         raise SystemExit("serve driver targets decoder-only archs")
     params = init_model(cfg, seed=0)

@@ -12,6 +12,8 @@ import jax
 
 from repro.configs import ARCH_IDS, get_config
 from repro.data.synthetic import lm_batches
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.optim.adamw import AdamWConfig
 from repro.train.loop import train
 
@@ -29,11 +31,12 @@ def main():
                     help="'data,model' sizes, e.g. '2,4' (needs devices)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=not args.full)
     mesh = None
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split(","))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
     print(f"[train] arch={cfg.name} smoke={not args.full} "
           f"steps={args.steps} devices={len(jax.devices())}")
     batches = lm_batches(cfg.vocab_size, args.batch, args.seq, seed=0)

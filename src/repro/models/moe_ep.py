@@ -33,19 +33,6 @@ from repro.kernels import ref as kref
 from repro.models.moe import route_masked
 
 
-def _shard_map(body, mesh, in_specs, out_specs):
-    """jax.shard_map across the pinned-jax spelling divide (see ROADMAP
-    'jax pinning'): new-style ``jax.shard_map(check_vma=)`` when the
-    installed jax has it, else the experimental ``check_rep=`` spelling."""
-    try:
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (TypeError, AttributeError):
-        from jax.experimental.shard_map import shard_map
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-
-
 def _ep_body(x, router, wg, wu, wd, *, cfg, msize, batch_axes, fsdp_axes,
              trust_mode, attack):
     """Per-device block. x: (B_l, S, d) local batch shard (replicated over
@@ -192,7 +179,8 @@ def moe_mlp_ep(params, x, cfg, mesh: Mesh, act_rules: dict, *,
     body = functools.partial(
         _ep_body, cfg=cfg, msize=msize, batch_axes=batch_axes,
         fsdp_axes=fsdp_axes, trust_mode=cfg.redundancy.mode, attack=attack)
-    mapped = _shard_map(body, mesh, in_specs, out_specs)
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     y, aux = mapped(x, params["router"], params["w_gate"], params["w_up"],
                     params["w_down"])
     if cfg.num_shared_experts:
@@ -209,8 +197,9 @@ def moe_mlp_ep(params, x, cfg, mesh: Mesh, act_rules: dict, *,
                 out = _ep_vote(yl.reshape(1, bl * s, dd),
                                cfg.redundancy.mode, attack)
                 return out.reshape(bl, s, dd)
-            y_sh = _shard_map(shared_body, mesh,
-                              (P(bspec, None, None),),
-                              P(bspec, None, None))(y_sh)
+            y_sh = jax.shard_map(shared_body, mesh=mesh,
+                                 in_specs=(P(bspec, None, None),),
+                                 out_specs=P(bspec, None, None),
+                                 check_vma=False)(y_sh)
         y = y + y_sh
     return y, aux * cfg.router_aux_weight

@@ -11,7 +11,8 @@ def test_trusted_moe_vote_recovers_under_attack(repo_src):
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.trusted_moe import make_trust, LMAttack
         from repro.models.config import RedundancyConfig
-        mesh = jax.make_mesh((1, 4, 2), ("data", "replica", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 4, 2), ("data", "replica", "model"))
         y = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 4, 16))
         for mode in ("faithful", "digest"):
             clean = make_trust(mesh, RedundancyConfig(4, mode), True, None)
@@ -48,7 +49,8 @@ def test_trusted_train_step_end_to_end(repo_src):
         cfg = get_config("bmoe-paper", smoke=True)
         cfg = dataclasses.replace(cfg,
             redundancy=RedundancyConfig(2, "faithful"), train_microbatches=1)
-        mesh = jax.make_mesh((1, 2, 2), ("data", "replica", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 2, 2), ("data", "replica", "model"))
         params = init_model(cfg, seed=0)
         opt = adamw.init(params)
         toks = jax.random.randint(jax.random.PRNGKey(0), (4, 32), 0,
@@ -83,7 +85,8 @@ def test_small_mesh_train_and_decode_compile(repo_src):
         from repro.train.loop import init_model
         from repro.train.step import make_step
         import dataclasses
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         for arch in ("qwen2-moe-a2.7b", "mamba2-2.7b", "gemma3-27b"):
             cfg = get_config(arch, smoke=True)
             cfg = dataclasses.replace(cfg, train_microbatches=1)
@@ -111,7 +114,8 @@ def test_hloanalysis_loop_correction(repo_src):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.launch import hloanalysis
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         W = jax.ShapeDtypeStruct((6, 128, 128), jnp.float32)
         X = jax.ShapeDtypeStruct((16, 128), jnp.float32)
         ws = NamedSharding(mesh, P(None, None, "model"))
@@ -150,7 +154,8 @@ def test_fsdp_param_rules(repo_src):
         import jax
         from repro.configs import get_config
         from repro.sharding import logical_rules
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_config("qwen3-32b")
         act = logical_rules(mesh, cfg)
         par = logical_rules(mesh, cfg, params=True)
@@ -180,7 +185,8 @@ def test_moe_ep_matches_gspmd_path(repo_src):
         x = jax.random.normal(jax.random.fold_in(key, 1),
                               (4, 32, cfg.d_model))
         y_ref, aux_ref = moe_lib.moe_mlp(params, x, cfg)   # no mesh
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = logical_rules(mesh, cfg)
         with mesh:
             y_ep, aux_ep = jax.jit(lambda p, x: moe_mlp_ep(
@@ -212,7 +218,8 @@ def test_moe_ep_trusted_vote(repo_src):
         params = materialize(moe_lib.moe_decl(cfg), key)
         x = jax.random.normal(jax.random.fold_in(key, 1),
                               (4, 16, cfg.d_model))
-        mesh = jax.make_mesh((1, 2, 4), ("data", "replica", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 2, 4), ("data", "replica", "model"))
         rules = logical_rules(mesh, cfg)
         for mode in ("faithful", "digest"):
             tcfg = dataclasses.replace(

@@ -213,6 +213,40 @@ def test_mesh_frameworks_bit_identical(repo_src):
     assert out.count("MESH OK") == 2
 
 
+def test_mesh_audit_recompute_runs_on_one_device(repo_src):
+    """A Pallas kernel cannot be partitioned over a mesh (the TPU
+    compiler refuses it; interpret mode on the CPU never notices), so
+    every commitment/audit recompute call must be a one-device program:
+    its committed arguments — the shard's expert slice and task — live
+    on the device owning that edge shard."""
+    out = run_with_devices(_COMMON + """
+        seen = []
+        s = BMoESystem(BMoEConfig(
+            framework="optimistic", dispatch="sparse", mesh="on",
+            mesh_shards=4, num_experts=8, top_k=2, pow_difficulty=2,
+            attack=AttackConfig(malicious_edges=(1,), attack_prob=1.0),
+            trust=TrustConfig(audit_rate=1.0, num_verifiers=2,
+                              challenge_window=1)))
+        call = s._batched_recompute_call
+        def checked(*args):
+            devs = set()
+            for leaf in jax.tree_util.tree_leaves(args):
+                if isinstance(leaf, jax.Array) and leaf.committed:
+                    devs |= set(leaf.sharding.device_set)
+            seen.append(frozenset(devs))
+            return call(*args)
+        s._batched_recompute_call = checked
+        for r in range(3):
+            s.train_round(xtr[r * 48:(r + 1) * 48], ytr[r * 48:(r + 1) * 48])
+        s.flush_trust()
+        assert s.protocol.stats["rolled_back"] >= 1
+        assert seen and all(len(d) == 1 for d in seen), seen
+        assert len(set(seen)) == 4, set(seen)       # one per edge shard
+        print("ONE DEVICE PER RECOMPUTE OK", len(seen))
+    """, 4, repo_src)
+    assert "ONE DEVICE PER RECOMPUTE OK" in out
+
+
 def test_mesh_bank_actually_sharded(repo_src):
     """The expert bank must really live sharded over the edge mesh (one
     E/msize slice per device), not replicated."""

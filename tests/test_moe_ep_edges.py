@@ -90,7 +90,8 @@ def test_ep_ragged_tokens_match_oracle(repo_src):
                                   padded_num_experts=4, moe_impl="ep")
         key = jax.random.PRNGKey(0)
         params = materialize(moe_lib.moe_decl(cfg), key)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = logical_rules(mesh, cfg)
         for S in (31, 7):
             x = jax.random.normal(jax.random.fold_in(key, S),
@@ -126,7 +127,8 @@ def test_ep_ragged_wire_bytes_parity(repo_src):
                                   padded_num_experts=4, moe_impl="ep")
         key = jax.random.PRNGKey(0)
         params = materialize(moe_lib.moe_decl(cfg), key)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = logical_rules(mesh, cfg)
         def bytes_for(S):
             x = jax.ShapeDtypeStruct((4, S, cfg.d_model), jnp.float32)
@@ -158,7 +160,8 @@ def test_ep_tiny_token_count(repo_src):
                                   padded_num_experts=4, moe_impl="ep")
         key = jax.random.PRNGKey(0)
         params = materialize(moe_lib.moe_decl(cfg), key)
-        mesh = jax.make_mesh((1, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 4), ("data", "model"))
         rules = logical_rules(mesh, cfg)
         for B, S in ((1, 1), (2, 1), (1, 3)):   # T_full < msize or ragged
             x = jax.random.normal(jax.random.fold_in(key, 10 * B + S),
@@ -194,7 +197,8 @@ def test_ep_digest_vote_agrees_with_faithful_when_honest(repo_src):
         params = materialize(moe_lib.moe_decl(cfg), key)
         x = jax.random.normal(jax.random.fold_in(key, 1),
                               (4, 16, cfg.d_model))
-        mesh = jax.make_mesh((1, 2, 4), ("data", "replica", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 2, 4), ("data", "replica", "model"))
         rules = logical_rules(mesh, cfg)
         ys = {}
         for mode in ("faithful", "digest"):
@@ -238,7 +242,8 @@ def test_ep_shared_expert_tamper_covered_by_vote(repo_src):
         params = materialize(moe_lib.moe_decl(cfg), key)
         x = jax.random.normal(jax.random.fold_in(key, 1),
                               (4, 16, cfg.d_model))
-        mesh = jax.make_mesh((1, 2, 4), ("data", "replica", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 2, 4), ("data", "replica", "model"))
         rules = logical_rules(mesh, cfg)
         def run(c, attack):
             with mesh:
