@@ -18,8 +18,9 @@ Measured per policy, from the engine's own metrics registry:
 - **goodput** — tokens/s of SLO-meeting requests (time-to-first-token
   within ``--slo-ticks`` engine ticks of submission) over measured
   serving wall-clock; also raw tokens/s and total engine ticks;
-- **token latency** — p50/p99 wall seconds per emitted token
-  (``serve.token_latency_s``);
+- **time to first token** — p50/p99 wall seconds from submission to
+  the first token on the host (``serve.ttft_s``), and p99 of the wait
+  from submission to admission (``serve.queue_wait_s``);
 - **slot occupancy** — mean/p50 of the per-tick occupied-slot fraction,
   plus mean time-to-first-token in ticks.
 
@@ -32,7 +33,7 @@ vs per-stream leaves).
 
 Writes ``BENCH_serving.json`` and exits non-zero (the CI gate) if
 continuous goodput does not beat fixed by ``--min-speedup``, if
-latency percentiles are missing, if the two schedules' token streams
+time-to-first-token percentiles are missing, if the two schedules' token streams
 differ, or if the verdict maps diverge.
 
 Env: ``REPRO_BENCH_SERVE_REQUESTS`` overrides the measured request
@@ -147,7 +148,7 @@ def measure(policy, cfg, params, requests, schedule, args):
     wall = rep["tick_s"]
     tokens = sum(len(v) for v in done.values())
     good_tokens = sum(len(done[rid]) for rid in slo_ok if rid in done)
-    lat = rep["token_latency"]
+    lat = rep["ttft"]
     return {
         "policy": policy,
         "driver_steps": steps,
@@ -159,8 +160,9 @@ def measure(policy, cfg, params, requests, schedule, args):
         "goodput_tok_s": good_tokens / max(wall, 1e-9),
         "slo_met_requests": len(slo_ok),
         "requests": len(done),
-        "token_latency_p50_s": lat["p50"],
-        "token_latency_p99_s": lat["p99"],
+        "ttft_p50_s": lat["p50"],
+        "ttft_p99_s": lat["p99"],
+        "queue_wait_p99_s": rep["queue_wait"]["p99"],
         "ttft_ticks_mean": float(np.mean(list(ttft.values()))) if ttft
         else 0.0,
         "occupancy_mean": rep["occupancy"]["mean"],
@@ -228,7 +230,7 @@ def main():
         r = results[policy]
         row(f"serve.{policy}", 1e6 * r["wall_s"] / max(r["tokens"], 1),
             f"goodput={r['goodput_tok_s']:.1f}tok/s "
-            f"p99={r['token_latency_p99_s'] * 1e3:.2f}ms "
+            f"ttft_p99={r['ttft_p99_s'] * 1e3:.2f}ms "
             f"occ={r['occupancy_mean']:.2f}")
 
     # trust contract: same verdict map under both schedules, honest and
@@ -300,8 +302,9 @@ def main():
         failures.append(f"goodput speedup {speedup:.3f} < "
                         f"{args.min_speedup} (continuous vs fixed)")
     for policy in ("continuous", "fixed"):
-        if results[policy]["token_latency_p99_s"] <= 0:
-            failures.append(f"{policy}: missing token latency percentiles")
+        if results[policy]["ttft_p99_s"] <= 0:
+            failures.append(f"{policy}: missing time-to-first-token "
+                            "percentiles")
     if not streams_equal:
         failures.append("token streams differ across schedules")
     if not verdicts_equal:

@@ -322,12 +322,13 @@ class BMoESystem:
         """One full Step 1-6 round on one published task (batch)."""
         cfg = self.cfg
         atk = attack if attack is not None else cfg.attack
-        rkey = jax.random.fold_in(jax.random.PRNGKey(cfg.seed + 17),
-                                  self.round)
-        mask_e = round_attack_mask(atk, cfg.num_edges, rkey)
-        executor = (self.protocol.pick_executor(self.round)
-                    if cfg.framework == "optimistic" else 0)
-        gate_bias, active = self._controls()
+        with self.obs.span("round-setup", round=self.round):
+            rkey = jax.random.fold_in(jax.random.PRNGKey(cfg.seed + 17),
+                                      self.round)
+            mask_e = round_attack_mask(atk, cfg.num_edges, rkey)
+            executor = (self.protocol.pick_executor(self.round)
+                        if cfg.framework == "optimistic" else 0)
+            gate_bias, active = self._controls()
         # the round span carries the on-path round seconds (off-path
         # audit drains nested below are excluded natively); every phase
         # below is its child, so one traced round decomposes into
@@ -354,25 +355,27 @@ class BMoESystem:
                     jnp.asarray(atk.colluding), gate_bias, active,
                     jnp.int32(executor))
                 metrics = jax.tree_util.tree_map(np.asarray, metrics)
-            self.gate_ema.update(metrics["activation"])
-
-            batch = int(x.shape[0])
-            payload = {
-                "round": self.round, "kind": "train",
-                "task": digest_array(np.asarray(x)[:8]),
-                "loss": float(metrics["loss"]),
-            }
-            # cost ledger in expert-evaluation units (one unit = one
-            # expert evaluated on one row of what it actually computes:
-            # the full batch under dense dispatch, its capacity bucket
-            # under sparse — the optimistic commitment covers exactly
-            # that buffer), so base/verify/escalate are all measured
-            # with the same yardstick
-            self.verify_stats["rounds"] += 1
-            if cfg.framework == "traditional":
-                self.verify_stats["base_evals"] += cfg.top_k * batch
-            else:
-                self.verify_stats["base_evals"] += self._exec_evals(batch)
+            with self.obs.span("bookkeeping", round=self.round):
+                self.gate_ema.update(metrics["activation"])
+                batch = int(x.shape[0])
+                payload = {
+                    "round": self.round, "kind": "train",
+                    "task": digest_array(np.asarray(x)[:8]),
+                    "loss": float(metrics["loss"]),
+                }
+                # cost ledger in expert-evaluation units (one unit = one
+                # expert evaluated on one row of what it actually
+                # computes: the full batch under dense dispatch, its
+                # capacity bucket under sparse — the optimistic
+                # commitment covers exactly that buffer), so
+                # base/verify/escalate are all measured with the same
+                # yardstick
+                self.verify_stats["rounds"] += 1
+                if cfg.framework == "traditional":
+                    self.verify_stats["base_evals"] += cfg.top_k * batch
+                else:
+                    self.verify_stats["base_evals"] += \
+                        self._exec_evals(batch)
             if cfg.framework != "optimistic":
                 # Step 5, chunked: publish the updated experts as new
                 # manifest versions (only routed experts changed;
@@ -425,10 +428,11 @@ class BMoESystem:
                 with self.obs.span("chain", metric="bmoe.chain_s",
                                    round=self.round):
                     self._mine(payload)
-            self._update_controllers(metrics)
-            self.activation_counts += metrics["activation"]
-            self.activation_total += batch * cfg.top_k
-            self.round += 1
+            with self.obs.span("bookkeeping", round=self.round):
+                self._update_controllers(metrics)
+                self.activation_counts += metrics["activation"]
+                self.activation_total += batch * cfg.top_k
+                self.round += 1
         return metrics
 
     def infer(self, x, *, attack: Optional[AttackConfig] = None,
@@ -1225,8 +1229,8 @@ class BMoESystem:
             if state.phase is not RoundPhase.CHALLENGED:
                 continue
             ctx = ctx_store[rid]
-            with self.obs.span("court", domain=domain, round=rid,
-                               executor=state.executor) as csp:
+            with self.obs.span("court", metric="bmoe.court_s", domain=domain,
+                               round=rid, executor=state.executor) as csp:
                 pub = self._court_publish(ctx, state.commitment.claimed,
                                           rid)
                 verdict = protocol.court.escalate(
@@ -1246,18 +1250,19 @@ class BMoESystem:
         summary["slashed"] = sorted(
             {ev.edge for ev in protocol.stakes.events[n_events:]})
         if summary["convicted"] and domain == "train":
-            with self.obs.span("rollback-replay",
+            with self.obs.span("rollback-replay", metric="bmoe.replay_s",
                                convicted=summary["convicted"]):
                 summary["replayed_metrics"] = self._replay_chain(
                     min(summary["convicted"]))
         for rec in protocol.rollbacks[n_rollbacks:]:
-            self._mine({"kind": "rollback", "domain": domain,
-                        "rollback_of": rec.round_id,
-                        "executor": rec.executor,
-                        "chain": [rec.round_id] + rec.invalidated,
-                        "invalidated": rec.invalidated,
-                        "slashed": [rec.executor],
-                        "at_round": self.round})
+            with self.obs.span("rollback-block", round=rec.round_id):
+                self._mine({"kind": "rollback", "domain": domain,
+                            "rollback_of": rec.round_id,
+                            "executor": rec.executor,
+                            "chain": [rec.round_id] + rec.invalidated,
+                            "invalidated": rec.invalidated,
+                            "slashed": [rec.executor],
+                            "at_round": self.round})
         return summary
 
     def _replay_chain(self, first: int):
@@ -1321,38 +1326,42 @@ class BMoESystem:
         behavior.  Returns the round's final metrics (the honest
         re-execution's, if rolled back)."""
         cfg, tc = self.cfg, self.trust_cfg
-        xin = np.asarray(x if cfg.expert_kind == "cnn"
-                         else np.asarray(x).reshape(len(x), -1))
-        batch = xin.shape[0]
-        row_index, bounds = self._commitment_layout(prev[0], x, batch,
-                                                    gate_bias)
-        honest = self._eager_outputs(prev[1], xin, bounds, row_index)
-        attacked = bool(np.asarray(mask_e)[executor] > 0)
-        state = self._commit_round(self.protocol, self.round, executor,
-                                   honest, attacked, atk, self.round,
-                                   payload["task"], row_index)
-        payload["commit_root"] = state.commitment.root[:16]
-        if state.commitment.routing_digest:
-            payload["routing"] = state.commitment.routing_digest[:16]
-        payload["executor"] = executor
+        with self.obs.span("commitment", metric="bmoe.commitment_s",
+                           round=self.round):
+            xin = np.asarray(x if cfg.expert_kind == "cnn"
+                             else np.asarray(x).reshape(len(x), -1))
+            batch = xin.shape[0]
+            row_index, bounds = self._commitment_layout(prev[0], x, batch,
+                                                        gate_bias)
+            honest = self._eager_outputs(prev[1], xin, bounds, row_index)
+            attacked = bool(np.asarray(mask_e)[executor] > 0)
+            state = self._commit_round(self.protocol, self.round, executor,
+                                       honest, attacked, atk, self.round,
+                                       payload["task"], row_index)
+            payload["commit_root"] = state.commitment.root[:16]
+            if state.commitment.routing_digest:
+                payload["routing"] = state.commitment.routing_digest[:16]
+            payload["executor"] = executor
         # data-availability contract: retain the expert versions this
         # round committed against until its window closes, and challenge
         # replica nodes for sampled chunks of exactly those manifests
-        manifests = self._retain_round_manifests(self.round)
-        self._audit_cids[self.round] = manifests
-        self._round_ctx[self.round] = {
-            "prev": prev, "x": x, "y": y, "xin": xin, "honest": honest,
-            "rkey": rkey, "executor": executor,
-            "mask_e": np.asarray(mask_e), "atk": atk,
-            "gate_bias": gate_bias, "active": active,
-            "manifests": manifests,
-        }
-        self._run_da(self.round, manifests)
-        recompute_fn = self._make_recompute(xin, manifests, row_index)
-        batch_fn = (self._make_batched_recompute(prev[1], xin, manifests,
-                                                 row_index)
-                    if tc.audit_backend == "batched" else None)
-        self.protocol.schedule_audit(self.round, recompute_fn, batch_fn)
+        with self.obs.span("da", metric="bmoe.da_s", round=self.round):
+            manifests = self._retain_round_manifests(self.round)
+            self._audit_cids[self.round] = manifests
+            self._round_ctx[self.round] = {
+                "prev": prev, "x": x, "y": y, "xin": xin, "honest": honest,
+                "rkey": rkey, "executor": executor,
+                "mask_e": np.asarray(mask_e), "atk": atk,
+                "gate_bias": gate_bias, "active": active,
+                "manifests": manifests,
+            }
+            self._run_da(self.round, manifests)
+        with self.obs.span("schedule-audit", round=self.round):
+            recompute_fn = self._make_recompute(xin, manifests, row_index)
+            batch_fn = (self._make_batched_recompute(prev[1], xin,
+                                                     manifests, row_index)
+                        if tc.audit_backend == "batched" else None)
+            self.protocol.schedule_audit(self.round, recompute_fn, batch_fn)
 
         # synchronous: the audit lands in the commit round itself (the
         # reference oracle); pipelined: drain only once a window forces it
@@ -1371,11 +1380,12 @@ class BMoESystem:
 
         # close windows in deadline order (sequential finality: never past
         # an unresolved dispute) and release closed rounds' evidence
-        finalized = self.protocol.advance(self.round)
-        if finalized:
-            payload["finalized_rounds"] = finalized
-        self._prune_closed_rounds(self.protocol, self._round_ctx,
-                                  self._audit_cids)
+        with self.obs.span("settle", round=self.round):
+            finalized = self.protocol.advance(self.round)
+            if finalized:
+                payload["finalized_rounds"] = finalized
+            self._prune_closed_rounds(self.protocol, self._round_ctx,
+                                      self._audit_cids)
 
         metrics = dict(metrics)
         metrics["rolled_back"] = np.float32(

@@ -22,7 +22,6 @@ from repro.kernels import rglru_scan as _rg
 from repro.kernels import ssd_scan as _ssd
 from repro.kernels import ref
 from repro.kernels.backend import default_backend
-from repro.obs import annotate
 
 __all__ = ["default_backend", "redundancy_vote", "moe_gemm", "audit_mlp",
            "flash_attention", "ssd_scan", "rglru_scan"]
@@ -60,7 +59,7 @@ def redundancy_vote(pub: jax.Array, axis: int = 1, *, atol: float = 0.0,
 # ------------------------------------------------------ grouped GEMM
 def moe_gemm(buf, w, *, backend: str | None = None):
     backend = backend or default_backend()
-    with annotate(f"moe_gemm[{backend}]"):
+    with jax.named_scope("moe_gemm"):
         if backend == "ref":
             return ref.moe_gemm_ref(buf, w)
         return _mg.moe_gemm(buf, w, interpret=(backend == "interpret"))
@@ -77,7 +76,7 @@ def audit_mlp(params, x, gid, *, backend: str | None = None):
     the relu in VMEM (validated allclose in tests/test_kernels.py).
     """
     backend = backend or default_backend()
-    with annotate(f"audit_mlp[{backend}]"):
+    with jax.named_scope("audit_mlp"):
         if backend == "ref":
             return ref.audit_mlp_ref(params, x, gid)
         return _ag.audit_mlp(params, x, gid,

@@ -254,36 +254,43 @@ def _mask_rows(mask, new, old):
 
 def _layer_decode(spec: LayerSpec, p, cache, x, pos, cfg, shard,
                   expert_stats=False, write_mask=None):
-    h = rmsnorm(x, p["norm1"], cfg.norm_eps)
-    if spec.kind in ("attn", "local_attn"):
-        window = cfg.sliding_window if spec.kind == "local_attn" else 0
-        y, new_cache = attn_decode(p["attn"], h, cache, pos, cfg,
-                                   window=window, shard=shard)
-    elif spec.kind == "rglru":
-        y, new_cache = rglru_lib.rglru_decode(p["rglru"], h, cache, cfg,
+    # named scopes land in each HLO op's ``op_name``, so the profiler's
+    # device ops can be told apart by the layer part that made them
+    attn = spec.kind in ("attn", "local_attn")
+    with jax.named_scope("attention" if attn else spec.kind):
+        h = rmsnorm(x, p["norm1"], cfg.norm_eps)
+        if attn:
+            window = cfg.sliding_window if spec.kind == "local_attn" else 0
+            y, new_cache = attn_decode(p["attn"], h, cache, pos, cfg,
+                                       window=window, shard=shard)
+        elif spec.kind == "rglru":
+            y, new_cache = rglru_lib.rglru_decode(p["rglru"], h, cache, cfg,
+                                                  shard=shard)
+        elif spec.kind == "ssm":
+            y, new_cache = ssm_lib.ssm_decode(p["ssm"], h, cache, cfg,
                                               shard=shard)
-    elif spec.kind == "ssm":
-        y, new_cache = ssm_lib.ssm_decode(p["ssm"], h, cache, cfg,
-                                          shard=shard)
     if write_mask is not None:
         # inactive slots (not decoding this step / past their prefill
         # length) must not advance KV rows or recurrent state
-        new_cache = jax.tree_util.tree_map(
-            lambda n, o: _mask_rows(write_mask, n, o), new_cache, cache)
+        with jax.named_scope("kv_write"):
+            new_cache = jax.tree_util.tree_map(
+                lambda n, o: _mask_rows(write_mask, n, o), new_cache, cache)
     x = x + y
     counts = None
     if spec.mlp != "none":
-        h = rmsnorm(x, p["norm2"], cfg.norm_eps)
-        if spec.mlp == "moe":
-            if expert_stats:
-                y, _, counts = moe_lib.moe_mlp(p["moe"], h, cfg, shard=shard,
-                                               return_stats=True)
+        with jax.named_scope("moe" if spec.mlp == "moe" else "mlp"):
+            h = rmsnorm(x, p["norm2"], cfg.norm_eps)
+            if spec.mlp == "moe":
+                if expert_stats:
+                    y, _, counts = moe_lib.moe_mlp(p["moe"], h, cfg,
+                                                   shard=shard,
+                                                   return_stats=True)
+                else:
+                    y, _ = moe_lib.moe_mlp(p["moe"], h, cfg, shard=shard)
             else:
-                y, _ = moe_lib.moe_mlp(p["moe"], h, cfg, shard=shard)
-        else:
-            y = swiglu(h, p["mlp"]["w_gate"], p["mlp"]["w_up"],
-                       p["mlp"]["w_down"], shard=shard)
-        x = x + y
+                y = swiglu(h, p["mlp"]["w_gate"], p["mlp"]["w_up"],
+                           p["mlp"]["w_down"], shard=shard)
+            x = x + y
     return x, new_cache, counts
 
 
@@ -341,9 +348,11 @@ def forward_decode(params, caches, tokens, pos, cfg: ModelConfig, *,
             new_caches["remainder"].append(nc)
             if c is not None:
                 counts.append(c[None])
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = x @ head
+    with jax.named_scope("head"):
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = x @ head
     if expert_stats:
         stats = (jnp.concatenate(counts, axis=0) if counts
                  else jnp.zeros((0, max(cfg.resolved_padded_experts, 1)),

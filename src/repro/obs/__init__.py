@@ -4,13 +4,11 @@ from repro.obs.metrics import (Counter, CounterGroup, Gauge, Histogram,
                                MetricsRegistry, DEFAULT_TIME_BUCKETS,
                                canonical_name, exp_buckets,
                                merge_namespaced)
-from repro.obs.trace import (NOOP_SPAN, Observability, Span, Tracer,
-                             annotate, annotations_enabled,
-                             set_annotations)
+from repro.obs.trace import NOOP_SPAN, Observability, Span, Tracer, now
 
 __all__ = [
     "Counter", "CounterGroup", "Gauge", "Histogram", "MetricsRegistry",
     "DEFAULT_TIME_BUCKETS", "canonical_name", "exp_buckets",
     "merge_namespaced", "NOOP_SPAN", "Observability", "Span", "Tracer",
-    "annotate", "annotations_enabled", "set_annotations",
+    "now",
 ]

@@ -9,7 +9,7 @@ Conventions:
 
 - metric *names* are dot-namespaced by layer (``bmoe.compute_s``,
   ``storage.cache.hits``, ``trust.train.finalized``,
-  ``serve.token_latency_s``); labels, when needed, are canonicalized
+  ``serve.ttft_s``); labels, when needed, are canonicalized
   into the name as ``name{k=v}``;
 - wall-clock metrics end in ``_s`` (host seconds); *modeled* seconds —
   deterministic cost-model output — end in ``modeled_*_s`` and are

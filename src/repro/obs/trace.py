@@ -10,9 +10,10 @@ metric.
 
 Three execution modes, chosen per span:
 
-- **no-op** — tracer disabled and the span carries no metric: a shared
-  singleton context manager is returned; nothing is timed, nothing is
-  allocated (the zero-overhead mode, bounded in tests/test_obs.py);
+- **no-op** — tracer disabled, the span carries no metric and no JAX
+  profiler capture is active: a shared singleton context manager is
+  returned; nothing is timed, nothing is allocated (the zero-overhead
+  mode, bounded in tests/test_obs.py);
 - **metric-only** — tracer disabled but the span feeds a phase counter
   (``metric="bmoe.consensus_s"``): the span is timed and participates
   in off-path accounting but records no trace event — this is the
@@ -20,6 +21,15 @@ Three execution modes, chosen per span:
   costs what the ``time.perf_counter()`` pairs it replaced cost;
 - **recording** — tracer enabled: the span is timed, stacked, and
   appended to the event log with its attributes for export.
+
+While a JAX profiler capture is active (``jax.profiler.start_trace``),
+every span — metric-bearing or not, recording or not — also opens a
+``jax.profiler.TraceAnnotation`` named ``repro.<span name>``, so the
+program's phases sit on the profiler's clock beside the device's ops
+and each idle gap of the device can be attributed to the host work
+inside it.  Whether a capture is active is
+``TraceAnnotation.is_enabled()``, a fraction of a microsecond; there
+is no switch of its own.
 
 Off-path accounting replaces the manual audit-seconds subtraction the
 pre-obs ``BMoESystem`` did by hand: a span opened with
@@ -37,13 +47,19 @@ every measurement flows through one substrate.
 from __future__ import annotations
 
 import json
-import os
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.metrics import MetricsRegistry
 
 _pc = time.perf_counter
+_capturing = TraceAnnotation.is_enabled
+
+#: the obs clock (seconds, monotonic): what span timings and the
+#: serving engine's request stamps read
+now = _pc
 
 
 class _NoopSpan:
@@ -67,7 +83,8 @@ NOOP_SPAN = _NoopSpan()
 class Span:
     """One timed region.  Use via ``with tracer.span(...) as sp:``."""
     __slots__ = ("tracer", "name", "metric", "off_path", "attrs", "span_id",
-                 "parent_id", "t0", "dur_s", "off_child_s", "_record")
+                 "parent_id", "t0", "dur_s", "off_child_s", "_record",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, metric: Optional[str],
                  off_path: bool, record: bool, attrs: Dict):
@@ -82,6 +99,7 @@ class Span:
         self.t0 = 0.0
         self.dur_s = 0.0
         self.off_child_s = 0.0
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         """Attach attributes discovered mid-span (block hash, verdicts)."""
@@ -95,11 +113,17 @@ class Span:
         stack = tr._stack
         self.parent_id = stack[-1].span_id if stack else 0
         stack.append(self)
+        if _capturing():
+            self._annotation = TraceAnnotation("repro." + self.name)
+            self._annotation.__enter__()
         self.t0 = _pc()
         return self
 
     def __exit__(self, *exc) -> bool:
         end = _pc()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         tr = self.tracer
         self.dur_s = end - self.t0
         stack = tr._stack
@@ -152,8 +176,11 @@ class Tracer:
         """Open a span.  ``metric``: phase counter fed on exit (seconds,
         off-path descendants excluded).  ``off_path=True``: this work is
         concurrent with the critical path in deployment — its seconds are
-        excluded from every enclosing span's metric."""
-        if not self.enabled and metric is None and not off_path:
+        excluded from every enclosing span's metric.  While a JAX
+        profiler capture is active every span is real, so that it
+        reaches the profiler's timeline."""
+        if (not self.enabled and metric is None and not off_path
+                and not _capturing()):
             return NOOP_SPAN
         return Span(self, name, metric, off_path, self.enabled, attrs)
 
@@ -202,36 +229,6 @@ class Tracer:
             for ev in self._events:
                 f.write(json.dumps(ev) + "\n")
         return len(self._events)
-
-
-# --------------------------------------------------- kernel annotations
-# jax.profiler.TraceAnnotation hooks around the grouped-GEMM hot paths:
-# when a jax profile is being captured, the annotation names the kernel
-# region on the device timeline.  Off by default (REPRO_OBS_ANNOTATE=1
-# or set_annotations(True) enables) so the hot path pays nothing.
-_annotate_enabled = os.environ.get("REPRO_OBS_ANNOTATE", "") not in ("", "0")
-
-
-def set_annotations(enabled: bool) -> None:
-    global _annotate_enabled
-    _annotate_enabled = bool(enabled)
-
-
-def annotations_enabled() -> bool:
-    return _annotate_enabled
-
-
-def annotate(name: str):
-    """Context manager naming a device-side region on the jax profiler
-    timeline (no-op unless annotations are enabled and jax exposes
-    ``profiler.TraceAnnotation``)."""
-    if not _annotate_enabled:
-        return NOOP_SPAN
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:                                 # pragma: no cover
-        return NOOP_SPAN
-    return TraceAnnotation(name)
 
 
 class Observability:

@@ -45,7 +45,7 @@ import numpy as np
 from repro.models import transformer as tfm
 from repro.models.builder import materialize
 from repro.models.config import ModelConfig
-from repro.obs import Observability
+from repro.obs import Observability, now
 from repro.obs.metrics import MetricsRegistry
 from repro.serve.scheduler import SlotScheduler, SlotState
 from repro.storage import (ExpertCache, ExpertStore, GateEMA,
@@ -380,10 +380,11 @@ class ServingEngine:
         return self.sched.queue
 
     @property
-    def request_meta(self) -> Dict[int, Dict[str, int]]:
-        """Per-request tick milestones: submitted/admitted/first-token/
-        finished — what the serving benchmark derives TTFT and queueing
-        delay from."""
+    def request_meta(self) -> Dict[int, Dict[str, float]]:
+        """Per-request milestones: submitted/admitted/first-token/
+        finished ticks, and on the obs clock (``repro.obs.now``) when
+        the request was submitted, admitted and its first token reached
+        the host (``-1`` until then)."""
         return self.sched.meta
 
     @property
@@ -410,7 +411,12 @@ class ServingEngine:
                 and not self.records[rid].revoked]
 
     def submit(self, requests: Iterable[dict]):
+        requests = list(requests)
         self.sched.submit(requests, self.tick)
+        t = now()
+        for r in requests:
+            self.sched.meta[r["id"]].update(submitted_s=t, admitted_s=-1.0,
+                                            first_token_s=-1.0)
 
     def warmup(self) -> int:
         """Compile every fused-step width bucket up front (the pow2s up
@@ -438,7 +444,15 @@ class ServingEngine:
         admitted = self.sched.admit(self.tick)
         if not admitted:
             return
-        self._reset_slot_caches([i for i, _ in admitted])
+        t = now()
+        wait = self.obs.metrics.histogram("serve.queue_wait_s")
+        for _, slot in admitted:
+            meta = self.sched.meta[slot.request_id]
+            if meta["admitted_s"] < 0:        # a readmission is not counted
+                meta["admitted_s"] = t
+                wait.observe(t - meta["submitted_s"])
+        with self.obs.span("reset-slots", slots=len(admitted)):
+            self._reset_slot_caches([i for i, _ in admitted])
         if self.kvrt is not None:
             for i, slot in admitted:
                 self._kv_on_admit(i, slot)
@@ -615,16 +629,18 @@ class ServingEngine:
         return rid
 
     # --------------------------------------------------------- emissions
-    def _emit(self, slot: SlotState, token: int, lat_s: float) -> None:
+    def _emit(self, slot: SlotState, token: int, host_s: float) -> None:
+        """Hand ``token`` to ``slot``'s stream; ``host_s`` is when the
+        chunk that made it reached the host (obs clock)."""
         slot.generated.append(token)
+        m = self.obs.metrics
         if len(slot.generated) == 1:
             slot.first_token_tick = self.tick
-            self.sched.meta[slot.request_id]["first_token_tick"] = self.tick
-        m = self.obs.metrics
+            meta = self.sched.meta[slot.request_id]
+            meta["first_token_tick"] = self.tick
+            meta["first_token_s"] = host_s
+            m.histogram("serve.ttft_s").observe(host_s - meta["submitted_s"])
         m.counter("serve.tokens").add(1)
-        m.histogram("serve.token_latency_s").observe(lat_s)
-        m.histogram("serve.token_latency_s",
-                    session=slot.request_id).observe(lat_s)
         if self.verified:
             self.records[slot.request_id].append(self.tick, token)
 
@@ -654,7 +670,14 @@ class ServingEngine:
         Per engine tick, host-side: emit, batch-commit the tick's
         Merkle leaf set, evict finished slots.  In verified mode, ticks
         keep running after the queue drains until every challenge
-        window has closed."""
+        window has closed.
+
+        Spans under ``step``: ``admit`` (``reset-slots``), ``prepare``
+        (chunk width, batch arrays), ``prefill``/``decode`` (``launch``:
+        the compiled call until it returns; ``wait``: until its tokens
+        are on the host), ``edge-cache`` and ``kv-prefetch`` when on,
+        ``replay`` (the per-tick host work: emit, ``commit``, finish,
+        window expiry with its ``audit-drain``), ``kv-da`` when on."""
         with self.obs.span("step", metric="serve.tick_s", tick=self.tick):
             return self._step_inner()
 
@@ -664,11 +687,59 @@ class ServingEngine:
             self._admit()
         if not self.sched.any_active:
             if self.verified and len(self._window):
-                self.tick += 1               # idle tick: windows still age
-                self._expire_windows()
+                with self.obs.span("replay", metric="serve.replay_s",
+                                   tick=self.tick):
+                    self.tick += 1           # idle tick: windows still age
+                    self._expire_windows()
                 return bool(len(self._window))
             return False
         self.steps += 1
+        with self.obs.span("prepare", tick=self.tick):
+            C, need, adv, batch = self._prepare()
+        prefill_now = (self.sched.policy == "continuous"
+                       and bool((need > 0).any()))
+        name, metric = (("prefill", "serve.prefill_s") if prefill_now
+                        else ("decode", "serve.decode_s"))
+        with self.obs.span(name, metric=metric, tick=self.tick, width=C):
+            with self.obs.span("launch"):
+                out = self._step_fn(self.params, self.caches, batch)
+                if self.edge is not None:
+                    outs, self.caches, stats = out
+                else:
+                    (outs, self.caches), stats = out, None
+            with self.obs.span("wait"):
+                outs = np.asarray(outs)      # (C, B) greedy next tokens
+        host_s = now()
+        if self.edge is not None and stats is not None:
+            # resolve the chunk's activated experts through the edge
+            # cache (cold: chunk fetches; warm: hits) + EMA prefetch
+            with self.obs.span("edge-cache"):
+                self.edge.on_tick(np.asarray(stats))
+        if self.kvrt is not None:
+            # overlap with the chunk just dispatched: warm queued
+            # requests' sealed prefix blocks into the cache
+            with self.obs.span("kv-prefetch"):
+                self._kv_macro_cids = []
+                self._kv_prefetch_queued()
+        with self.obs.span("replay", metric="serve.replay_s",
+                           tick=self.tick, width=C):
+            self._replay(C, need, adv, outs, host_s)
+        if self.kvrt is not None and self.kvrt.da is not None \
+                and self._kv_macro_cids:
+            # DA challenges over the KV chunks sealed this macro-step:
+            # replica nodes answer for sealed KV exactly like expert
+            # chunks (corrupt -> slash + repair; withheld -> window)
+            with self.obs.span("kv-da"):
+                seen = sorted(set(self._kv_macro_cids))
+                self.kvrt.da.challenge_round(self.tick,
+                                             self.kvrt.kv.manifests(seen))
+                self.kvrt.da.resolve(self.tick)
+        return True
+
+    def _prepare(self):
+        """The macro-step's chunk width ``C`` and its inputs: per slot the
+        prompt tokens it consumes (``need``) and the micro-steps it
+        advances (``adv``), and the device batch."""
         m = self.obs.metrics
         m.histogram("serve.occupancy").observe(self.sched.occupancy())
         m.gauge("serve.queue_depth").set(self.sched.depth())
@@ -720,29 +791,13 @@ class ServingEngine:
         batch = {"tokens": jnp.asarray(tokens), "start": jnp.asarray(start),
                  "pos": jnp.asarray(pos), "lengths": jnp.asarray(need),
                  "adv": jnp.asarray(adv)}
-        prefill_now = continuous and bool((need > 0).any())
-        name, metric = (("prefill", "serve.prefill_s") if prefill_now
-                        else ("decode", "serve.decode_s"))
-        with self.obs.span(name, metric=metric, tick=self.tick,
-                           width=C) as sp:
-            out = self._step_fn(self.params, self.caches, batch)
-            if self.edge is not None:
-                outs, self.caches, stats = out
-            else:
-                (outs, self.caches), stats = out, None
-            outs = np.asarray(outs)          # (C, B) greedy next tokens
-        if self.edge is not None and stats is not None:
-            # resolve the chunk's activated experts through the edge
-            # cache (cold: chunk fetches; warm: hits) + EMA prefetch
-            self.edge.on_tick(np.asarray(stats))
-        if self.kvrt is not None:
-            # overlap with the chunk just dispatched: warm queued
-            # requests' sealed prefix blocks into the cache
-            self._kv_macro_cids = []
-            self._kv_prefetch_queued()
-        lat = sp.dur_s / C
+        return C, need, adv, batch
 
-        # ---- replay the chunk host-side, one engine tick per micro-step
+    def _replay(self, C: int, need: np.ndarray, adv: np.ndarray,
+                outs: np.ndarray, host_s: float) -> None:
+        """Replay the chunk host-side, one engine tick per micro-step:
+        emit, seal KV blocks, commit, finish, expire windows."""
+        slots = self.sched.slots
         for t in range(C):
             self.tick += 1
             emissions: List[Tuple[int, int, int]] = []  # (slot, rid, tok)
@@ -755,11 +810,11 @@ class ServingEngine:
                     s.pos += 1
                     if s.cursor == len(s.prompt):
                         tok = int(outs[t, i])   # first generated token
-                        self._emit(s, tok, lat)
+                        self._emit(s, tok, host_s)
                         emissions.append((i, s.request_id, tok))
                 elif int(adv[i]) == C and s.cursor >= len(s.prompt):
                     tok = int(outs[t, i])    # autoregressive continuation
-                    self._emit(s, tok, lat)
+                    self._emit(s, tok, host_s)
                     emissions.append((i, s.request_id, tok))
                     s.pos += 1
             if self.kvrt is not None:
@@ -781,16 +836,6 @@ class ServingEngine:
                     self._finish(i)
             if self.verified:
                 self._expire_windows()
-        if self.kvrt is not None and self.kvrt.da is not None \
-                and self._kv_macro_cids:
-            # DA challenges over the KV chunks sealed this macro-step:
-            # replica nodes answer for sealed KV exactly like expert
-            # chunks (corrupt -> slash + repair; withheld -> window)
-            seen = sorted(set(self._kv_macro_cids))
-            self.kvrt.da.challenge_round(self.tick,
-                                         self.kvrt.kv.manifests(seen))
-            self.kvrt.da.resolve(self.tick)
-        return True
 
     def _commit_tick(self, emissions: List[Tuple[int, int, int]]) -> None:
         """One Merkle append for the whole batch tick: a tree over every
@@ -823,8 +868,9 @@ class ServingEngine:
     # ------------------------------------------------------- observability
     def obs_report(self) -> Dict:
         """Serving-side view over the metrics registry: tick/token
-        throughput, wall-clock totals per phase, token-latency
-        percentiles (aggregate and per session), slot occupancy, the
+        throughput, wall-clock totals per phase, queue-wait and
+        time-to-first-token percentiles (submission to admission, and
+        to the first token on the host), slot occupancy, the
         batched-commitment append counters, plus the edge storage
         section when edge expert storage is on."""
         m = self.obs.metrics
@@ -836,15 +882,13 @@ class ServingEngine:
             "prefill_s": float(m.value("serve.prefill_s")),
             "decode_s": float(m.value("serve.decode_s")),
             "commit_s": float(m.value("serve.commit_s")),
+            "replay_s": float(m.value("serve.replay_s")),
             "audit_offpath_s": float(m.value("serve.audit_s")),
-            "token_latency": m.histogram("serve.token_latency_s").snapshot(),
+            "queue_wait": m.histogram("serve.queue_wait_s").snapshot(),
+            "ttft": m.histogram("serve.ttft_s").snapshot(),
             "occupancy": m.histogram("serve.occupancy").snapshot(),
             "commit_appends": int(m.value("serve.commit.appends")),
             "commit_leaves": int(m.value("serve.commit.leaves")),
-            "sessions": {
-                name.split("session=", 1)[1].rstrip("}"): snap
-                for name, snap in
-                m.snapshot("serve.token_latency_s{").items()},
         }
         if self.edge is not None:
             out["edge"] = self.edge.report()

@@ -5,9 +5,13 @@ Timing inside these tests goes through metric-bearing spans (the
 subsystem measures itself) — direct wall-clock call sites outside
 ``src/repro/obs/`` and ``benchmarks/common.py`` are CI-linted away.
 """
+import contextlib
+import glob
 import json
+import os
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -92,7 +96,30 @@ def test_chrome_trace_roundtrip(tmp_path):
     assert lines == obs.trace.events
 
 
-def test_noop_mode_zero_allocation_and_bounded():
+@contextlib.contextmanager
+def _capture(logdir):
+    """A JAX profiler capture into ``logdir``."""
+    jax.profiler.start_trace(str(logdir))
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def _program_events(logdir):
+    """(name, start_ns, duration_ns) of every ``repro.*`` host event of
+    the capture in ``logdir``."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(str(logdir), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    return [(e.name, e.start_ns, e.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if not plane.name.startswith("/device:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("repro.")]
+
+
+def test_noop_mode_zero_allocation_and_bounded(tmp_path):
     obs = Observability()                        # disabled
     assert not obs.enabled
     # no metric, not off-path -> the shared singleton: nothing allocated
@@ -111,6 +138,17 @@ def test_noop_mode_zero_allocation_and_bounded():
             with obs.span("hot", round=1):
                 pass
     assert meter.metrics.value("m.bound_s") < 0.5   # <10us per no-op span
+    # a profiler capture makes every span real, so that it reaches the
+    # profiler's timeline; it still records nothing and books no metric
+    with _capture(tmp_path):
+        sp = obs.span("phase", round=1)
+        assert sp is not NOOP_SPAN
+        with sp:
+            pass
+    assert obs.span("phase") is NOOP_SPAN
+    assert obs.trace.events == []
+    assert set(obs.metrics.snapshot()) == {"m.t_s"}
+    assert [e[0] for e in _program_events(tmp_path)] == ["repro.phase"]
 
 
 # ------------------------------------------------------------- metrics
@@ -283,8 +321,9 @@ def test_metrics_deterministic_and_blocks_unpolluted():
 
 
 def test_serving_engine_token_latency_report():
-    """Per-tick spans + per-session token-latency histograms on the
-    serving engine, and the edge runtime's legacy report keys."""
+    """Per-tick spans, one queue-wait and one time-to-first-token
+    observation per request on the obs clock, and the edge runtime's
+    legacy report keys."""
     from repro.configs import get_config
     from repro.data.synthetic import serving_requests
     from repro.serve.engine import EdgeStorageConfig, ServingEngine
@@ -296,7 +335,7 @@ def test_serving_engine_token_latency_report():
     eng = ServingEngine(cfg, params, batch_slots=2, cache_len=32,
                         expert_storage=EdgeStorageConfig(
                             cache_bytes=1 << 20), obs=obs)
-    reqs = list(serving_requests(cfg.vocab_size, 2, max_prompt=8,
+    reqs = list(serving_requests(cfg.vocab_size, 3, max_prompt=8,
                                  max_new=3, seed=0))
     eng.submit(reqs)
     done = eng.run(max_ticks=50)
@@ -304,14 +343,28 @@ def test_serving_engine_token_latency_report():
     assert rep == eng.obs_report()
     emitted = int(obs.metrics.value("serve.tokens"))
     assert emitted >= sum(len(v) for v in done.values()) > 0
-    assert rep["token_latency"]["count"] == emitted
+    assert "token_latency" not in rep and "sessions" not in rep
+    # one observation per admitted and per first-token request (three
+    # requests through two slots: the third waits for a free slot)
+    meta = eng.request_meta
+    assert all(meta[r["id"]]["first_token_tick"] >= 0 for r in reqs)
+    assert rep["queue_wait"]["count"] == len(reqs)
+    assert rep["ttft"]["count"] == len(reqs)
+    for r in reqs:
+        m = meta[r["id"]]
+        assert m["submitted_s"] <= m["admitted_s"] <= m["first_token_s"]
+    waits = sorted(meta[r["id"]]["admitted_s"] - meta[r["id"]]["submitted_s"]
+                   for r in reqs)
+    assert rep["queue_wait"]["max"] == pytest.approx(waits[-1])
+    assert waits[-1] > waits[0]                  # the third one queued
+    assert rep["ttft"]["sum"] == pytest.approx(sum(
+        meta[r["id"]]["first_token_s"] - meta[r["id"]]["submitted_s"]
+        for r in reqs))
     # a fused macro-step books to prefill_s while any prompt token is
     # in flight and to decode_s otherwise; short requests may generate
     # entirely inside prefill chunks, so assert over the pair
     assert rep["tick_s"] >= rep["prefill_s"] + rep["decode_s"] > 0
-    # one latency histogram per served session, observations summing up
-    assert set(rep["sessions"]) == {str(r["id"]) for r in reqs}
-    assert sum(s["count"] for s in rep["sessions"].values()) == emitted
+    assert rep["tick_s"] >= rep["replay_s"] > 0
     # the edge runtime's legacy report shape is unchanged
     assert set(rep["edge"]) == {"cache", "store", "network", "units",
                                 "ticks"}
@@ -323,3 +376,130 @@ def test_serving_engine_token_latency_report():
     steps = [e for e in obs.trace.events if e["name"] == "step"]
     assert len(steps) >= eng.steps > 0
     assert eng.tick >= eng.steps
+
+
+# ------------------------------------------------- profiler timeline
+
+ROUND_SPANS = {"round-setup", "round", "fetch", "dispatch", "bookkeeping",
+               "consensus", "commitment", "da", "schedule-audit",
+               "audit-drain", "settle", "publish", "chain"}
+STEP_SPANS = {"step", "admit", "reset-slots", "prepare", "prefill",
+              "launch", "wait", "replay", "commit"}
+
+
+def _verified_engine(obs=None):
+    from repro.configs import get_config
+    from repro.serve.engine import ServingEngine
+    from repro.train.loop import init_model
+    cfg = get_config("smollm-360m", smoke=True)
+    params = init_model(cfg, seed=0)
+    eng = ServingEngine(cfg, params, batch_slots=2, cache_len=32,
+                        prefill_chunk=4, obs=obs,
+                        trust=TrustConfig(audit_rate=1.0, num_verifiers=1,
+                                          challenge_window=2))
+    rng = np.random.default_rng(0)
+    eng.submit([{"id": i, "prompt": rng.integers(0, cfg.vocab_size, 3),
+                 "max_new_tokens": 4} for i in range(3)])
+    return eng
+
+
+@pytest.fixture(scope="module")
+def captured(tmp_path_factory):
+    """Rounds and one engine macro-step under a profiler capture, with
+    tracing off, beside the same rounds run without a capture."""
+    logdir = tmp_path_factory.mktemp("capture")
+    plain = _run(seed=0)
+    eng = _verified_engine()
+    with _capture(logdir):
+        rounds = _run(seed=0)
+        eng.step()
+    return plain, rounds, _program_events(logdir)
+
+
+def test_capture_puts_round_and_step_spans_on_the_profiler(captured):
+    _, _, events = captured
+    names = {n for n, _, _ in events}
+    assert {"repro." + n for n in ROUND_SPANS | STEP_SPANS} <= names
+    rounds = [e for e in events if e[0] == "repro.round"]
+    assert len(rounds) == R
+    # every phase of a round lies inside one of the rounds
+    for name, t, dur in events:
+        if name in ("repro.fetch", "repro.commitment", "repro.settle"):
+            assert any(r[1] <= t and t + dur <= r[1] + r[2]
+                       for r in rounds)
+
+
+def test_capture_leaves_ledger_blocks_bit_identical(captured):
+    plain, rounds, _ = captured
+    assert all("trace_id" not in b.payload for b in rounds.ledger.blocks)
+    assert [b.hash for b in rounds.ledger.blocks] \
+        == [b.hash for b in plain.ledger.blocks]
+
+
+def _on_path_sum(ev, name):
+    """Seconds of the spans ``name``, less the off-path spans nested at
+    any depth inside them."""
+    ids = {e["span_id"] for e in ev if e["name"] == name}
+    parent = {e["span_id"]: e["parent_id"] for e in ev}
+
+    def inside(e):
+        p = e["parent_id"]
+        while p and p not in ids:
+            p = parent.get(p, 0)
+        return bool(p)
+    return (sum(e["dur_s"] for e in ev if e["name"] == name)
+            - sum(e["dur_s"] for e in ev if e["off_path"] and inside(e)))
+
+
+def test_round_phase_metrics_unchanged_by_nested_spans(traced_system):
+    """The spans nested in ``consensus`` and ``round`` feed their own
+    metrics and leave every enclosing metric at its span's on-path
+    wall time."""
+    s, obs = traced_system
+    ev = obs.trace.events
+    m = obs.metrics.value
+    assert m("bmoe.consensus_s") == pytest.approx(
+        _on_path_sum(ev, "consensus"), rel=1e-9)
+    assert m("bmoe.storage_s") == pytest.approx(
+        sum(e["dur_s"] for e in ev if e["name"] in ("fetch", "publish")),
+        rel=1e-9)
+    for span, metric in (("commitment", "bmoe.commitment_s"),
+                         ("da", "bmoe.da_s"), ("court", "bmoe.court_s"),
+                         ("rollback-replay", "bmoe.replay_s")):
+        assert m(metric) == pytest.approx(
+            sum(e["dur_s"] for e in ev if e["name"] == span), rel=1e-9)
+    assert m("bmoe.replay_s") > 0 and m("bmoe.court_s") > 0
+    assert m("bmoe.commitment_s") + m("bmoe.da_s") < m("bmoe.consensus_s")
+    # the round's direct children cover it, and so do consensus's
+    for parent in ("round", "consensus"):
+        for p in (e for e in ev if e["name"] == parent):
+            child = sum(e["dur_s"] for e in ev
+                        if e["parent_id"] == p["span_id"])
+            assert child >= 0.9 * p["dur_s"], parent
+
+
+def test_step_phase_metrics_unchanged_by_nested_spans():
+    obs = Observability(enabled=True)
+    eng = _verified_engine(obs)
+    eng.run(max_ticks=80)
+    ev = obs.trace.events
+    m = obs.metrics.value
+    assert {e["name"] for e in ev} >= STEP_SPANS | {"audit-drain"}
+    for span, metric in (("prefill", "serve.prefill_s"),
+                         ("decode", "serve.decode_s"),
+                         ("commit", "serve.commit_s")):
+        assert m(metric) == pytest.approx(
+            sum(e["dur_s"] for e in ev if e["name"] == span), rel=1e-9)
+    assert m("serve.tick_s") == pytest.approx(_on_path_sum(ev, "step"),
+                                              rel=1e-9)
+    # the audit drain, off the path, nests in the replay, whose metric
+    # leaves it out just as the step's does
+    replay_ids = {e["span_id"] for e in ev if e["name"] == "replay"}
+    assert any(e["parent_id"] in replay_ids for e in ev
+               if e["name"] == "audit-drain")
+    assert m("serve.replay_s") == pytest.approx(_on_path_sum(ev, "replay"),
+                                                rel=1e-9)
+    for name in ("prefill", "decode"):
+        for p in (e for e in ev if e["name"] == name):
+            kids = {e["name"] for e in ev if e["parent_id"] == p["span_id"]}
+            assert kids == {"launch", "wait"}
