@@ -8,7 +8,7 @@ from __future__ import annotations
 
 from typing import Dict
 
-from lm_weights import param_count, weight_bytes
+from lm_weights import param_count, streamed_bytes
 
 
 def kv_row_bytes(c: Dict, itemsize: int = 4) -> int:
@@ -44,11 +44,14 @@ def positions_flops(c: Dict, positions) -> int:
 
 def serve_microstep_bytes(c: Dict, positions, itemsize: int = 4) -> int:
     """HBM bytes one micro-step of the serve step must move: the weights
-    once, each busy slot's cached keys and values up to its position,
-    and the one row it writes.  ``positions``: the position each busy
-    slot processes in this micro-step."""
+    as ``lm_weights.streamed_bytes`` counts them (each product's weight
+    at the width its products read it, the busy slots' embedding rows,
+    the norm scales), each busy slot's cached keys and values up to its
+    position, and the one row it writes.  ``positions``: the position
+    each busy slot processes in this micro-step."""
     row = kv_row_bytes(c, itemsize)
-    return weight_bytes(c, itemsize) + sum((p + 2) * row for p in positions)
+    return (streamed_bytes(c, 1, len(positions))
+            + sum((p + 2) * row for p in positions))
 
 
 def runs_kv_bytes(c: Dict, positions, itemsize: int = 4) -> int:
@@ -56,6 +59,16 @@ def runs_kv_bytes(c: Dict, positions, itemsize: int = 4) -> int:
     runs ``(start, stop)`` of processed positions."""
     row = kv_row_bytes(c, itemsize)
     return sum(row * ((a + 2 + b + 1) * (b - a) // 2) for a, b in positions)
+
+
+def serve_step_bytes(c: Dict, ticks: int, positions,
+                     itemsize: int = 4) -> int:
+    """``serve_microstep_bytes`` summed over a macro-step of ``ticks``
+    micro-steps whose busy slots process the contiguous runs ``(start,
+    stop)`` of positions, in closed form."""
+    rows = sum(b - a for a, b in positions)
+    return (streamed_bytes(c, ticks, rows)
+            + runs_kv_bytes(c, positions, itemsize))
 
 
 def round_sample_flops(c: Dict) -> int:

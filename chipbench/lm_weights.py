@@ -50,8 +50,60 @@ def _leaves(tree, prefix=()) -> list:
     return [(prefix, tree)]
 
 
-def weight_bytes(c: Dict, itemsize: int = 4) -> int:
-    return sum(int(np.prod(s)) * itemsize for _, (s, _) in _leaves(shapes(c)))
+# bytes of one element of each type a configuration's ``torch_dtype``
+# may name
+DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2}
+
+# the narrowest width, in bytes, at which a matrix product at each of
+# JAX's precisions (by either of its names) reads an operand on TPU: one
+# bfloat16 pass reads bfloat16; three passes read a float32 split into
+# two bfloat16 halves; "highest" reads float32
+PRECISION_BYTES = {"default": 2, "bfloat16": 2, "high": 4,
+                   "tensorfloat32": 4, "highest": 4, "float32": 4}
+
+# leaves that feed no matrix product: the embedding is a row lookup,
+# the norm scales multiply elementwise
+GATHERED = "embed"
+ELEMENTWISE = ("final_norm", "norm1", "norm2")
+
+
+def stored_width(c: Dict) -> int:
+    """Bytes of one stored weight, from the file's ``torch_dtype``."""
+    return DTYPE_BYTES[c["torch_dtype"]]
+
+
+def product_width(c: Dict) -> int:
+    """Bytes at which the matrix products read a weight: the file's
+    ``matmul_precision`` reads it no wider than it is stored."""
+    if c["matmul_precision"] not in PRECISION_BYTES:
+        raise KeyError(f"no operand width for matmul_precision "
+                       f"{c['matmul_precision']!r}; known: "
+                       f"{sorted(PRECISION_BYTES)}")
+    return min(stored_width(c), PRECISION_BYTES[c["matmul_precision"]])
+
+
+def weight_bytes(c: Dict) -> int:
+    """The weights' stored size: what they hold of the device's memory."""
+    return sum(int(np.prod(s)) for _, (s, _) in _leaves(shapes(c))) \
+        * stored_width(c)
+
+
+def streamed_bytes(c: Dict, ticks: int, rows: int) -> int:
+    """Least HBM bytes of weights that ``ticks`` micro-steps of the serve
+    step read, which gather ``rows`` embedding rows in all (one per busy
+    slot and micro-step): every matrix product's weight once per
+    micro-step at ``product_width`` (a conversion to it need not repeat,
+    since the weights do not change), the norm scales once per micro-step
+    and the gathered rows, both at ``stored_width``."""
+    stored, product = stored_width(c), product_width(c)
+    per_tick = 0
+    for path, (shape, _) in _leaves(shapes(c)):
+        if path[-1] == GATHERED:
+            row = shape[-1] * stored
+        else:
+            per_tick += int(np.prod(shape)) * (
+                stored if path[-1] in ELEMENTWISE else product)
+    return ticks * per_tick + rows * row
 
 
 def key_for(seed: int) -> jax.Array:

@@ -25,9 +25,9 @@ def step_flops(c: Dict, s: Dict) -> int:
 
 def step_bytes(c: Dict, s: Dict) -> int:
     """HBM bytes the macro-step's micro-steps must move: the weights
-    once per micro-step, and each busy slot's cache rows."""
-    from lm_weights import weight_bytes
-    return s["ticks"] * weight_bytes(c) + costs.runs_kv_bytes(c, s["runs"])
+    once per micro-step at the width the products read them, the busy
+    slots' embedding rows, and each busy slot's cache rows."""
+    return costs.serve_step_bytes(c, s["ticks"], s["runs"])
 
 
 def traced_steps(w) -> List[Dict]:

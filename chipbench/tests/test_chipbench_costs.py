@@ -51,12 +51,64 @@ def test_flops_closed_form_matches_token_sum():
     assert costs.token_flops(C, 0) == 2 * 525_369_344 + 16_384
 
 
+# weights one micro-step streams, under "default" (one bfloat16 pass):
+# the product weights, attention 41,943,040 + router 32,768 + 8 experts
+# 1,409,286,144 + head 131,072,000 = 1,582,333,952 parameters, at 2
+# bytes (3,164,667,904); the norm scales, 3 * 4096 at 4 bytes (49,152);
+# the embedding only in the rows the busy slots gather, 4096 * 4 each
+PRODUCT_PARAMS = 41_943_040 + 32_768 + 1_409_286_144 + 131_072_000
+STREAMED_PER_TICK = 2 * PRODUCT_PARAMS + 4 * 3 * 4096
+EMBED_ROW = 4096 * 4
+
+
+def test_streamed_weight_bytes():
+    assert PRODUCT_PARAMS == 1_582_333_952
+    assert STREAMED_PER_TICK == 3_164_717_056
+    assert lm_weights.streamed_bytes(C, 1, 0) == STREAMED_PER_TICK
+    # 32 busy slots: 3,165,241,344 bytes, 3.86 ms at 819 GB/s
+    assert lm_weights.streamed_bytes(C, 1, 32) == (STREAMED_PER_TICK
+                                                   + 32 * EMBED_ROW)
+    assert lm_weights.streamed_bytes(C, 16, 512) == 16 * (
+        STREAMED_PER_TICK + 32 * EMBED_ROW)
+    # one micro-step of 32 slots at positions 0..31: the weights above,
+    # and slot p reads p + 1 cached rows and writes one (8,192 bytes each)
+    assert costs.serve_microstep_bytes(C, range(32)) == (
+        STREAMED_PER_TICK + 32 * EMBED_ROW
+        + sum(p + 2 for p in range(32)) * 2 * 8 * 128 * 4)
+
+
+@pytest.mark.parametrize("precision, stored, width", [
+    ("default", "float32", 2), ("highest", "float32", 4),
+    ("high", "float32", 4), ("highest", "bfloat16", 2)])
+def test_product_width_follows_the_file(precision, stored, width):
+    c = dict(C, matmul_precision=precision, torch_dtype=stored)
+    assert lm_weights.product_width(c) == width
+    norms_and_row = lm_weights.stored_width(c) * (3 * 4096 + 4096)
+    assert lm_weights.streamed_bytes(c, 1, 1) == (width * PRODUCT_PARAMS
+                                                  + norms_and_row)
+
+
+def test_highest_streams_the_stored_float32():
+    c = dict(C, matmul_precision="highest")
+    # every weight but the embedding, at 4 bytes: the stored size less
+    # the table, plus the one row gathered
+    assert lm_weights.streamed_bytes(c, 1, 1) == (
+        lm_weights.weight_bytes(C) - 4 * 32000 * 4096 + EMBED_ROW)
+
+
+def test_unknown_precision_is_refused():
+    with pytest.raises(KeyError, match="matmul_precision"):
+        lm_weights.product_width(dict(C, matmul_precision="exact"))
+
+
 def test_bytes_closed_form_matches_microsteps():
     runs = [(5, 9), (0, 3)]
     per_step = [costs.serve_microstep_bytes(C, [5 + t, t]) for t in range(3)]
     per_step.append(costs.serve_microstep_bytes(C, [8]))
-    assert sum(per_step) == (4 * lm_weights.weight_bytes(C)
+    # 4 micro-steps, 7 positions processed
+    assert sum(per_step) == (4 * STREAMED_PER_TICK + 7 * EMBED_ROW
                              + costs.runs_kv_bytes(C, runs))
+    assert costs.serve_step_bytes(C, 4, runs) == sum(per_step)
 
 
 def test_weight_layout_is_the_programs():

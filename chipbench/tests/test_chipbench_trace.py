@@ -1,6 +1,9 @@
 """Trace reduction on small hand-made traces: busy union, loops that
 hold other ops, clipping to the traced window, idle gaps labelled by
-the benchmark's annotations."""
+the benchmark's annotations; and the serve step's roofline read from
+such a trace."""
+from types import SimpleNamespace
+
 import pytest
 
 import bench_paths  # noqa: F401  (first: the import path)
@@ -78,3 +81,34 @@ def test_recorded_v5e_slice():
     assert names[0] == "%bitcast_dynamic-update-slice_fusion.3"
     assert not any(n.startswith(tr.CONTAINER_OPS) for n in names)
     assert s["idle_gaps"] == []
+
+
+@pytest.mark.parametrize("share, reads", [(1.0, 100.0), (0.5, 200.0)])
+def test_serve_step_roofline_against_its_floor(share, reads):
+    """Two traced macro-steps of 16 micro-steps, 32 busy slots each:
+    a serve-step program that takes exactly the least time the count
+    allows reads 100%, one that takes half of it 200%."""
+    import costs
+    import readers
+    import run
+    from peaks import peaks
+    c, peak = run.load_config("mixtral-8x7b"), peaks("TPU v5 lite")
+    steps = [{"model": True, "ticks": 16, "runs": [(p, p + 16)] * 32}
+             for p in (100, 1000)]
+    floor_s = 0.0
+    for s in steps:
+        moved = costs.serve_step_bytes(c, s["ticks"], s["runs"])
+        ops = costs.positions_flops(c, s["runs"])
+        assert moved / peak["hbm_bytes_s"] > ops / peak["bf16_flops_s"]
+        floor_s += moved / peak["hbm_bytes_s"]
+    dev_ns = share * floor_s * 1e9
+    events = [ev(HOST, "python3", "chipbench.traced", 0, 2 * dev_ns + 1e6),
+              ev(DEV, "XLA Modules", "jit_serve_chunk_step(7)", 1e5,
+                 dev_ns / 2),
+              ev(DEV, "XLA Modules", "jit_serve_chunk_step(7)",
+                 2e5 + dev_ns / 2, dev_ns / 2)]
+    w = SimpleNamespace(
+        c=c, peak=peak, trace_summary=tr.reduce(events),
+        trace=SimpleNamespace(done=True, first_step=1, last_step=3),
+        driver=SimpleNamespace(steps=[{"model": False}] + steps))
+    assert readers.serve_step_roofline_percent(w) == pytest.approx(reads)
