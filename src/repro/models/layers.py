@@ -236,61 +236,67 @@ def _quantize_kv(t):
     return q, scale
 
 
-def attn_decode(params, x, cache, pos, cfg, *, window=0, shard=None):
+def attn_decode(params, x, cache, pos, cfg, *, window=0, shard=None,
+                mask=None, layer=None):
     """One-token decode. cache: {"k": (B,cap,KH,D), "v": ...} (+ optional
     int8 "k_scale"/"v_scale" when cfg.kv_cache_dtype == "int8").
 
     ``pos`` is an int32 scalar (every row at the same depth — the
     batch-synchronous path) or a (B,) vector (continuous batching: each
-    row writes/reads its own cache slot).  Returns (out, new_cache).
+    row writes/reads its own cache slot).  ``mask`` (B,) bool: rows where
+    it is False write nothing (their row's update is dropped), so the
+    cache is updated in place, one row per writing slot.  ``layer``: the
+    cache leaves are stacked over layers; this layer writes at
+    ``[layer, row, slot]`` and reads its own slice.  Returns (out,
+    new_cache), ``new_cache`` shaped like ``cache``.
     """
     B = x.shape[0]
+    if mask is not None:
+        pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     vec = jnp.ndim(pos) > 0
     positions = (jnp.reshape(pos, (B, 1)).astype(jnp.int32) if vec
                  else jnp.full((B, 1), pos, jnp.int32))
     q, k, v = attn_qkv(params, x, positions, cfg)
-    cap = cache["k"].shape[1]
+    cap = cache["k"].shape[-3]
     slot = (pos % cap) if window else jnp.minimum(pos, cap - 1)
     kv_seq_ax = "cache_seq" if not window else "kv_seq"
-    quantized = "k_scale" in cache
+    lead = () if layer is None else (layer,)
 
     if vec:
         rows = jnp.arange(B)
+        if mask is not None:
+            slot = jnp.where(mask, slot, cap)      # out of range: dropped
 
         def put(buf, val):           # per-row scatter: row b writes slot[b]
-            return buf.at[rows, slot].set(val[:, 0])
+            return buf.at[lead + (rows, slot)].set(val[:, 0], mode="drop")
     else:
         def put(buf, val):
-            return jax.lax.dynamic_update_slice_in_dim(buf, val, slot,
-                                                       axis=1)
+            start = lead + (0, slot) + (0,) * (val.ndim - 2)
+            return jax.lax.dynamic_update_slice(
+                buf, val.reshape((1,) * len(lead) + val.shape), start)
 
-    if quantized:  # §Perf iteration 4: int8 cache halves HBM cache reads
-        kq, ks = _quantize_kv(k)
-        vq, vs = _quantize_kv(v)
-        new_cache = {
-            "k": put(cache["k"], kq),
-            "v": put(cache["v"], vq),
-            "k_scale": put(cache["k_scale"], ks),
-            "v_scale": put(cache["v_scale"], vs),
-        }
-        if shard is not None:
-            new_cache["k"] = shard(new_cache["k"], "batch", kv_seq_ax,
-                                   "kv_heads", "head_dim")
-            new_cache["v"] = shard(new_cache["v"], "batch", kv_seq_ax,
-                                   "kv_heads", "head_dim")
-        k_cache = (new_cache["k"].astype(jnp.float32)
-                   * new_cache["k_scale"][..., None]).astype(x.dtype)
-        v_cache = (new_cache["v"].astype(jnp.float32)
-                   * new_cache["v_scale"][..., None]).astype(x.dtype)
+    def read(buf):                   # this layer's (B, cap, ...) slice
+        return buf if layer is None else buf[layer]
+
+    with jax.named_scope("kv_write"):
+        if "k_scale" in cache:   # §Perf iteration 4: int8 cache halves reads
+            kq, ks = _quantize_kv(k)
+            vq, vs = _quantize_kv(v)
+            new_cache = {"k": put(cache["k"], kq), "v": put(cache["v"], vq),
+                         "k_scale": put(cache["k_scale"], ks),
+                         "v_scale": put(cache["v_scale"], vs)}
+        else:
+            new_cache = {"k": put(cache["k"], k), "v": put(cache["v"], v)}
+    if "k_scale" in cache:
+        k_cache = (read(new_cache["k"]).astype(jnp.float32)
+                   * read(new_cache["k_scale"])[..., None]).astype(x.dtype)
+        v_cache = (read(new_cache["v"]).astype(jnp.float32)
+                   * read(new_cache["v_scale"])[..., None]).astype(x.dtype)
     else:
-        k_cache = put(cache["k"], k)
-        v_cache = put(cache["v"], v)
-        if shard is not None:
-            k_cache = shard(k_cache, "batch", kv_seq_ax, "kv_heads",
-                            "head_dim")
-            v_cache = shard(v_cache, "batch", kv_seq_ax, "kv_heads",
-                            "head_dim")
-        new_cache = {"k": k_cache, "v": v_cache}
+        k_cache, v_cache = read(new_cache["k"]), read(new_cache["v"])
+    if shard is not None:
+        k_cache = shard(k_cache, "batch", kv_seq_ax, "kv_heads", "head_dim")
+        v_cache = shard(v_cache, "batch", kv_seq_ax, "kv_heads", "head_dim")
     out = decode_attention(q, k_cache, v_cache, pos, window=window,
                            softcap=cfg.attn_logit_softcap)
     out = out.reshape(B, 1, cfg.q_dim) @ params["wo"]

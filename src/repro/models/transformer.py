@@ -253,28 +253,37 @@ def _mask_rows(mask, new, old):
 
 
 def _layer_decode(spec: LayerSpec, p, cache, x, pos, cfg, shard,
-                  expert_stats=False, write_mask=None):
+                  expert_stats=False, write_mask=None, layer=None):
+    """One layer of a decode step.  ``layer``: ``cache`` is the block
+    stack's cache (leading layer axis) and this layer's state sits at
+    ``[layer]``; it is written there, so the stack updates in place."""
     # named scopes land in each HLO op's ``op_name``, so the profiler's
     # device ops can be told apart by the layer part that made them
     attn = spec.kind in ("attn", "local_attn")
     with jax.named_scope("attention" if attn else spec.kind):
         h = rmsnorm(x, p["norm1"], cfg.norm_eps)
         if attn:
+            # inactive slots (not decoding this step / past their prefill
+            # length) write no KV row: the mask drops their update
             window = cfg.sliding_window if spec.kind == "local_attn" else 0
             y, new_cache = attn_decode(p["attn"], h, cache, pos, cfg,
-                                       window=window, shard=shard)
-        elif spec.kind == "rglru":
-            y, new_cache = rglru_lib.rglru_decode(p["rglru"], h, cache, cfg,
-                                                  shard=shard)
-        elif spec.kind == "ssm":
-            y, new_cache = ssm_lib.ssm_decode(p["ssm"], h, cache, cfg,
-                                              shard=shard)
-    if write_mask is not None:
-        # inactive slots (not decoding this step / past their prefill
-        # length) must not advance KV rows or recurrent state
-        with jax.named_scope("kv_write"):
-            new_cache = jax.tree_util.tree_map(
-                lambda n, o: _mask_rows(write_mask, n, o), new_cache, cache)
+                                       window=window, shard=shard,
+                                       mask=write_mask, layer=layer)
+        else:
+            own = (cache if layer is None else
+                   jax.tree_util.tree_map(lambda a: a[layer], cache))
+            decode = (rglru_lib.rglru_decode if spec.kind == "rglru"
+                      else ssm_lib.ssm_decode)
+            y, new_cache = decode(p[spec.kind], h, own, cfg, shard=shard)
+            with jax.named_scope("kv_write"):
+                if write_mask is not None:
+                    # inactive slots keep their recurrent state
+                    new_cache = jax.tree_util.tree_map(
+                        lambda n, o: _mask_rows(write_mask, n, o),
+                        new_cache, own)
+                if layer is not None:
+                    new_cache = jax.tree_util.tree_map(
+                        lambda a, n: a.at[layer].set(n), cache, new_cache)
     x = x + y
     counts = None
     if spec.mlp != "none":
@@ -314,29 +323,27 @@ def forward_decode(params, caches, tokens, pos, cfg: ModelConfig, *,
         x = shard(x, "batch", "seq", "embed")
     n_moe_blk = sum(1 for s in cfg.block_pattern if s.mlp == "moe")
 
-    def body(x, inp):
-        blk, cch = inp
-        new_cch = {}
+    # the stacked block cache rides in the layer scan's carry and each
+    # layer writes its rows at [layer, ...]: nothing rebuilds the stack
+    def body(carry, inp):
+        x, cch = carry
+        blk, layer = inp
+        cch = dict(cch)
         cnts = []
         for i, spec in enumerate(cfg.block_pattern):
-            x, new_cch[str(i)], c = _layer_decode(
+            x, cch[str(i)], c = _layer_decode(
                 spec, blk[str(i)], cch[str(i)], x, pos, cfg, shard,
-                expert_stats=expert_stats, write_mask=write_mask)
+                expert_stats=expert_stats, write_mask=write_mask,
+                layer=layer)
             if c is not None:
                 cnts.append(c)
-        if expert_stats and cnts:
-            return x, (new_cch, jnp.stack(cnts))
-        return x, (new_cch, None) if expert_stats else new_cch
+        return (x, cch), (jnp.stack(cnts) if cnts else None)
 
-    x, ys = scan_or_unroll(body, x, (params["blocks"], caches["blocks"]),
-                           unroll)
-    if expert_stats:
-        new_block_caches, blk_counts = ys
-        counts = ([blk_counts.reshape(-1, blk_counts.shape[-1])]
-                  if n_moe_blk else [])
-    else:
-        new_block_caches = ys
-        counts = []
+    (x, new_block_caches), blk_counts = scan_or_unroll(
+        body, (x, caches["blocks"]),
+        (params["blocks"], jnp.arange(cfg.resolved_num_blocks)), unroll)
+    counts = ([blk_counts.reshape(-1, blk_counts.shape[-1])]
+              if expert_stats and n_moe_blk else [])
     new_caches = {"blocks": new_block_caches}
     if cfg.remainder:
         new_caches["remainder"] = []
