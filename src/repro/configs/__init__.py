@@ -21,6 +21,7 @@ ARCH_IDS = (
     "qwen2-moe-a2.7b",
     "mamba2-2.7b",
     "bmoe-paper",            # the paper's own MoE setup at LM scale
+    "moonlight-16b-a3b",
 )
 
 _MODULES = {
@@ -35,6 +36,7 @@ _MODULES = {
     "qwen2-moe-a2.7b": "qwen2_moe_a2_7b",
     "mamba2-2.7b": "mamba2_2_7b",
     "bmoe-paper": "bmoe_paper",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 

@@ -4,7 +4,9 @@ dense / MoE / SSM / hybrid (RG-LRU) / VLM / audio enc-dec.
 A model is a repeating ``block_pattern`` of :class:`LayerSpec` scanned
 ``num_blocks`` times (scan-over-layers keeps HLO size O(1) in depth, which
 is what keeps the 512-device dry-run compile tractable), plus an unrolled
-``remainder`` for depths that don't divide the pattern.
+``remainder`` for depths that don't divide the pattern, and unrolled
+``leading`` layers ahead of the pattern (a model whose first layers are
+dense, DeepSeek-V3 style).
 """
 from __future__ import annotations
 
@@ -14,7 +16,7 @@ from typing import Tuple
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
-    kind: str = "attn"          # attn | local_attn | rglru | ssm
+    kind: str = "attn"          # attn | local_attn | mla | rglru | ssm
     mlp: str = "dense"          # dense | moe | none
 
 
@@ -67,10 +69,18 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     sliding_window: int = 1024         # window for local_attn layers
     attn_logit_softcap: float = 0.0
+    # --- multi-head latent attention (``mla`` layers, DeepSeek-V2/V3):
+    # keys and values come from one cached latent of ``kv_lora_rank``
+    # and a rotary key of ``qk_rope_head_dim`` shared by all heads
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- layer pattern ---
     block_pattern: Tuple[LayerSpec, ...] = (LayerSpec(),)
     num_blocks: int = 0                # 0 -> num_layers // len(block_pattern)
     remainder: Tuple[LayerSpec, ...] = ()
+    leading: Tuple[LayerSpec, ...] = ()     # unrolled, ahead of the pattern
     # --- MoE ---
     num_experts: int = 0
     num_experts_per_tok: int = 0
@@ -90,6 +100,16 @@ class ModelConfig:
     moe_d_ff: int = 0                  # routed-expert hidden size
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # router: "softmax" (top-k of the softmax) or "sigmoid" (top-k of
+    # sigmoid scores, plus a per-expert selection bias ``b_corr`` used
+    # only to choose: DeepSeek-V3's aux-free balancing); the chosen
+    # weights renormalized to sum 1, then scaled
+    router_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # the routed experts this device holds (expert parallelism's share;
+    # () = all): the router still scores all ``num_experts``, and only
+    # the held experts' part of the routed output is computed here
+    held_experts: Tuple[int, ...] = ()
     # --- SSM (Mamba-2 SSD) ---
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -116,6 +136,9 @@ class ModelConfig:
     train_microbatches: int = 1
     # --- numerics ---
     norm_eps: float = 1e-6
+    # precision of the steps' matrix products, as JAX names it:
+    # "default" (one bfloat16 pass on a TPU), "high" (three), "highest"
+    matmul_precision: str = "default"
     tie_embeddings: bool = False
     citation: str = ""
 
@@ -142,6 +165,20 @@ class ModelConfig:
         return max(self.padded_num_experts, self.num_experts)
 
     @property
+    def resolved_held_experts(self) -> Tuple[int, ...]:
+        return self.held_experts or tuple(range(self.resolved_padded_experts))
+
+    @property
+    def expert_share(self) -> bool:
+        """True when this device holds only some of the routed experts."""
+        return bool(self.held_experts)
+
+    @property
+    def all_layers(self) -> Tuple[LayerSpec, ...]:
+        """Every distinct layer spec: leading, pattern and remainder."""
+        return self.leading + self.block_pattern + self.remainder
+
+    @property
     def ssm_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -157,7 +194,8 @@ class ModelConfig:
     def resolved_num_blocks(self) -> int:
         if self.num_blocks:
             return self.num_blocks
-        return (self.num_layers - len(self.remainder)) // len(self.block_pattern)
+        return ((self.num_layers - len(self.leading) - len(self.remainder))
+                // len(self.block_pattern))
 
     @property
     def is_encoder_decoder(self) -> bool:
@@ -165,8 +203,7 @@ class ModelConfig:
 
     @property
     def attention_free(self) -> bool:
-        specs = self.block_pattern + self.remainder
-        return all(s.kind in ("ssm", "rglru") for s in specs)
+        return all(s.kind in ("ssm", "rglru") for s in self.all_layers)
 
     @property
     def subquadratic(self) -> bool:
@@ -178,18 +215,31 @@ class ModelConfig:
         decode because the dominant cache is windowed and the rare global
         caches shard over the mesh.
         """
-        specs = self.block_pattern + self.remainder
-        n_global = sum(1 for s in specs if s.kind == "attn")
+        specs = self.all_layers
+        n_global = sum(1 for s in specs if s.kind in ("attn", "mla"))
         return n_global == 0 or (n_global / len(specs)) <= 0.2
 
     def validate(self):
-        n = self.resolved_num_blocks * len(self.block_pattern) + len(self.remainder)
+        n = (len(self.leading) + self.resolved_num_blocks * len(self.block_pattern)
+             + len(self.remainder))
         if n != self.num_layers:
             raise ValueError(
-                f"{self.name}: pattern x blocks + remainder = {n} != num_layers {self.num_layers}")
-        if any(s.mlp == "moe" for s in self.block_pattern + self.remainder):
+                f"{self.name}: leading + pattern x blocks + remainder = {n} != num_layers {self.num_layers}")
+        if any(s.mlp == "moe" for s in self.all_layers):
             if not (self.num_experts and self.num_experts_per_tok and self.moe_d_ff):
                 raise ValueError(f"{self.name}: MoE layers need expert config")
+        if any(s.kind == "mla" for s in self.all_layers) and not (
+                self.kv_lora_rank and self.qk_nope_head_dim
+                and self.qk_rope_head_dim and self.v_head_dim):
+            raise ValueError(f"{self.name}: mla layers need their latent sizes")
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"{self.name}: router_scoring "
+                             f"{self.router_scoring!r}")
+        held = self.held_experts
+        if held and (len(set(held)) != len(held) or min(held) < 0
+                     or max(held) >= self.num_experts):
+            raise ValueError(f"{self.name}: held_experts {held} is not a set "
+                             f"of the {self.num_experts} experts")
         return self
 
 

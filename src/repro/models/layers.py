@@ -49,7 +49,8 @@ def blockwise_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
                         q_offset=0, q_chunk=512, kv_chunk=512):
     """Online-softmax attention.
 
-    q: (B, Sq, H, D); k, v: (B, Sk, KH, D) with H = KH * G.
+    q: (B, Sq, H, D); k: (B, Sk, KH, D), v: (B, Sk, KH, Dv) with
+    H = KH * G (``Dv`` may differ from ``D``, as in latent attention).
     ``window`` > 0 limits attention to the last ``window`` keys (sliding
     window, inclusive of self).  ``q_offset``: absolute position of q[0]
     relative to k[0] (for chunked prefill; 0 for plain self-attention).
@@ -57,6 +58,7 @@ def blockwise_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
     """
     B, Sq, H, D = q.shape
     _, Sk, KH, _ = k.shape
+    Dv = v.shape[-1]
     G = H // KH
     scale = D ** -0.5
 
@@ -72,7 +74,7 @@ def blockwise_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
 
     qc = q.reshape(B, nq, q_chunk, KH, G, D)
     kc = k.reshape(B, nk, kv_chunk, KH, D)
-    vc = v.reshape(B, nk, kv_chunk, KH, D)
+    vc = v.reshape(B, nk, kv_chunk, KH, Dv)
 
     def q_step(_, qi):
         qblk = qc[:, qi]  # (B, qc, KH, G, D)
@@ -101,7 +103,7 @@ def blockwise_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
                 preferred_element_type=jnp.float32)
             return (acc_new, m_new, l_new), None
 
-        acc0 = jnp.zeros((B, KH, G, q_chunk, D), jnp.float32)
+        acc0 = jnp.zeros((B, KH, G, q_chunk, Dv), jnp.float32)
         m0 = jnp.full((B, KH, G, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KH, G, q_chunk), jnp.float32)
         (acc, m, l), _ = jax.lax.scan(kv_step, (acc0, m0, l0),
@@ -110,9 +112,9 @@ def blockwise_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
         return None, out.astype(q.dtype)  # (B, KH, G, qc, D)
 
     _, outs = jax.lax.scan(jax.checkpoint(q_step), None, jnp.arange(nq))
-    # outs: (nq, B, KH, G, qc, D) -> (B, Sq, H, D)
-    out = jnp.moveaxis(outs, 0, 1).reshape(B, nq, H, q_chunk, D)
-    out = out.transpose(0, 1, 3, 2, 4).reshape(B, Sq, H, D)
+    # outs: (nq, B, KH, G, qc, Dv) -> (B, Sq, H, Dv)
+    out = jnp.moveaxis(outs, 0, 1).reshape(B, nq, H, q_chunk, Dv)
+    out = out.transpose(0, 1, 3, 2, 4).reshape(B, Sq, H, Dv)
     return out
 
 
@@ -180,6 +182,24 @@ def attn_decl(cfg) -> dict:
         decl["q_norm"] = Leaf((hd,), ("head_dim",), "zeros")
         decl["k_norm"] = Leaf((hd,), ("head_dim",), "zeros")
     return decl
+
+
+def mla_decl(cfg) -> dict:
+    """Latent attention without a query LoRA: ``wq`` gives each head's
+    query (``qk_nope_head_dim`` + ``qk_rope_head_dim``); ``wkva`` gives
+    the latent (``kv_lora_rank``, normed by ``kva_norm``) and one rotary
+    key shared by all heads; ``wkvb`` expands the latent to each head's
+    key part (``qk_nope_head_dim``) and value (``v_head_dim``)."""
+    d, H = cfg.d_model, cfg.num_heads
+    nope, rope_d = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    c, dv = cfg.kv_lora_rank, cfg.v_head_dim
+    return {
+        "wq": Leaf((d, H * (nope + rope_d)), ("embed", "q_dim")),
+        "wkva": Leaf((d, c + rope_d), ("embed", None)),
+        "kva_norm": Leaf((c,), (None,), "zeros"),
+        "wkvb": Leaf((c, H * (nope + dv)), (None, "q_dim")),
+        "wo": Leaf((H * dv, d), ("q_dim", "embed")),
+    }
 
 
 def mlp_decl(cfg) -> dict:
@@ -301,3 +321,91 @@ def attn_decode(params, x, cache, pos, cfg, *, window=0, shard=None,
                            softcap=cfg.attn_logit_softcap)
     out = out.reshape(B, 1, cfg.q_dim) @ params["wo"]
     return out, new_cache
+
+
+# --------------------------------------------------- latent attention
+def _mla_project(params, x, positions, cfg):
+    """Queries, normed latents and rotated shared keys of ``x`` (B, S, d)
+    at ``positions`` (B, S): q_nope (B,S,H,nope), q_pe (B,S,H,rope),
+    c (B,S,kv_lora_rank), k_pe (B,S,rope)."""
+    B, S, _ = x.shape
+    H, nope = cfg.num_heads, cfg.qk_nope_head_dim
+    q = (x @ params["wq"]).reshape(B, S, H, -1)
+    kva = x @ params["wkva"]
+    c = rmsnorm(kva[..., :cfg.kv_lora_rank], params["kva_norm"],
+                cfg.norm_eps)
+    k_pe = rope(kva[..., None, cfg.kv_lora_rank:], positions,
+                cfg.rope_theta)[..., 0, :]
+    return (q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta),
+            c, k_pe)
+
+
+def _mla_up(params, cfg):
+    """``wkvb`` split per head: (kv_lora_rank, H, nope) and (.., H, v)."""
+    w = params["wkvb"].reshape(cfg.kv_lora_rank, cfg.num_heads, -1)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def mla_train(params, x, cfg, *, q_chunk=512, kv_chunk=512):
+    """Latent attention over a whole sequence, expanded: each head's key
+    is [latent up-projection | the shared rotary key], its value the
+    latent's up-projection."""
+    B, S, _ = x.shape
+    positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+    q_nope, q_pe, c, k_pe = _mla_project(params, x, positions, cfg)
+    w_uk, w_uv = _mla_up(params, cfg)
+    k_nope = jnp.einsum("bsc,chn->bshn", c, w_uk)
+    v = jnp.einsum("bsc,chv->bshv", c, w_uv)
+    H = cfg.num_heads
+    q = jnp.concatenate([q_nope, q_pe], -1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_pe[:, :, None], (B, S, H, k_pe.shape[-1]))],
+        -1)
+    out = blockwise_attention(q, k, v, causal=True, q_chunk=q_chunk,
+                              kv_chunk=kv_chunk)
+    return out.reshape(B, S, H * cfg.v_head_dim) @ params["wo"]
+
+
+def mla_decode(params, x, cache, pos, cfg, *, mask=None, layer=None):
+    """One-token latent attention against the latent cache, absorbed:
+    the query's key part is taken into the latent space
+    (``q_nope . w_uk^T``) and the weighted sum of latents is expanded by
+    ``w_uv`` after the softmax, so each cached position is read as its
+    ``kv_lora_rank`` latent and its rotary key, never as per-head keys
+    and values.  Exactly the expanded form, reassociated.
+
+    cache: {"ckv": (B, cap, kv_lora_rank), "kpe": (B, cap, rope)}, with
+    a leading layer axis when ``layer`` is given (this layer writes at
+    ``[layer, row, slot]``).  ``pos``: (B,) or scalar; ``mask`` (B,)
+    bool: rows where it is False write nothing (their index is sent
+    out of range and dropped).  Returns (out (B, 1, d), new_cache)."""
+    B = x.shape[0]
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    q_nope, q_pe, c, k_pe = _mla_project(params, x, pos[:, None], cfg)
+    cap = cache["ckv"].shape[-2]
+    slot = jnp.minimum(pos, cap - 1)
+    if mask is not None:
+        slot = jnp.where(mask, slot, cap)          # out of range: dropped
+    lead = () if layer is None else (layer,)
+    rows = jnp.arange(B)
+    with jax.named_scope("kv_write"):
+        new_cache = {
+            "ckv": cache["ckv"].at[lead + (rows, slot)].set(c[:, 0],
+                                                           mode="drop"),
+            "kpe": cache["kpe"].at[lead + (rows, slot)].set(k_pe[:, 0],
+                                                           mode="drop")}
+    ckv = new_cache["ckv"] if layer is None else new_cache["ckv"][layer]
+    kpe = new_cache["kpe"] if layer is None else new_cache["kpe"][layer]
+    w_uk, w_uv = _mla_up(params, cfg)
+    q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0], w_uk)
+    s = (jnp.einsum("bhc,btc->bht", q_lat, ckv,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bhr,btr->bht", q_pe[:, 0], kpe,
+                      preferred_element_type=jnp.float32))
+    s = s * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    s = jnp.where(jnp.arange(cap)[None, None, :] <= pos[:, None, None], s,
+                  NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    o_lat = jnp.einsum("bht,btc->bhc", p.astype(ckv.dtype), ckv)
+    o = jnp.einsum("bhc,chv->bhv", o_lat, w_uv)
+    return o.reshape(B, 1, -1) @ params["wo"], new_cache

@@ -17,18 +17,25 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.builder import Leaf
 
 
 def moe_decl(cfg) -> dict:
+    """The router scores all ``num_experts``; the expert banks hold only
+    the held experts (all of them unless ``cfg.held_experts``), in the
+    order ``held_experts`` lists them."""
     E, d, f = cfg.resolved_padded_experts, cfg.d_model, cfg.moe_d_ff
+    Eh = len(cfg.resolved_held_experts)
     decl = {
         "router": Leaf((d, E), ("embed", "experts"), scale=0.02),
-        "w_gate": Leaf((E, d, f), ("experts", "embed", "moe_ff")),
-        "w_up": Leaf((E, d, f), ("experts", "embed", "moe_ff")),
-        "w_down": Leaf((E, f, d), ("experts", "moe_ff", "embed")),
+        "w_gate": Leaf((Eh, d, f), ("experts", "embed", "moe_ff")),
+        "w_up": Leaf((Eh, d, f), ("experts", "embed", "moe_ff")),
+        "w_down": Leaf((Eh, f, d), ("experts", "moe_ff", "embed")),
     }
+    if cfg.router_scoring == "sigmoid":
+        decl["b_corr"] = Leaf((E,), ("experts",), "zeros")
     if cfg.num_shared_experts:
         sf = cfg.num_shared_experts * f
         decl["shared"] = {
@@ -66,11 +73,18 @@ def capacity_positions(expert_id, num_experts: int, capacity: int):
     return position, position < capacity, onehot
 
 
-def route(logits, k: int, capacity: int, num_real: int = 0):
+def route(logits, k: int, capacity: int, num_real: int = 0, *,
+          scoring: str = "softmax", bias=None, scale: float = 1.0):
     """logits: (B, S, E).  Per-row top-k routing with capacity buckets.
 
     ``num_real`` < E masks the padded experts (expert-axis padding for
     even model-axis sharding) out of the softmax/top-k.
+
+    ``scoring="softmax"``: the top k of the softmax.  ``"sigmoid"``
+    (DeepSeek-V3): scores ``sigmoid(logits)`` in float32, the top k of
+    ``scores + bias`` chosen (``bias`` (E,) steers selection only) and
+    weighted by their scores.  The k weights are renormalized to sum 1;
+    ``scale`` multiplies them after.
 
     Returns weights (B,S,k), expert_id (B,S,k), position (B,S,k),
     keep (B,S,k) and the GShard load-balance aux loss."""
@@ -78,9 +92,20 @@ def route(logits, k: int, capacity: int, num_real: int = 0):
     if num_real and num_real < E:
         pad_mask = jnp.arange(E) >= num_real
         logits = jnp.where(pad_mask[None, None, :], -1e30, logits)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    weights, expert_id = jax.lax.top_k(probs, k)
-    weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        weights, expert_id = jax.lax.top_k(probs, k)
+        weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+    else:
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+        choice = scores if bias is None else scores + bias.astype(jnp.float32)
+        _, expert_id = jax.lax.top_k(choice, k)
+        weights = jnp.take_along_axis(scores, expert_id, axis=-1)
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+        # the load-balance statistic reads each token's scores as shares
+        probs = scores / scores.sum(-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
 
     position, keep, onehot = capacity_positions(
         expert_id.reshape(B, S * k), E, capacity)
@@ -157,8 +182,15 @@ def grouped_mlp(buf, w_gate, w_up, w_down, shard=None):
     return jnp.einsum("becf,efd->becd", h, w_down)
 
 
-def moe_mlp(params, x, cfg, shard=None, trust=None, return_stats=False):
+def moe_mlp(params, x, cfg, shard=None, trust=None, return_stats=False,
+            return_held=False):
     """x: (B, S, d) -> (B, S, d), plus aux loss.
+
+    With an expert share (``cfg.held_experts``) the router scores and
+    chooses over all experts and weights the chosen k as usual; only the
+    assignments to held experts are dispatched (capacity buffers for the
+    held experts alone), so ``y`` is the held experts' weighted part plus
+    the shared experts: what this device adds to the layer.
 
     ``trust``: optional hook applied to the routed-expert output buffer —
     the B-MoE redundancy + consensus vote.
@@ -167,15 +199,31 @@ def moe_mlp(params, x, cfg, shard=None, trust=None, return_stats=False):
     ``(E,)`` (drops included — a dropped assignment still computed its
     bucket, so its expert's parameters were needed).  This is the gate
     statistic the serving engine's edge cache feeds its EMA prefetcher
-    with; default off so existing (y, aux) call sites are untouched."""
+    with; default off so existing (y, aux) call sites are untouched.
+
+    ``return_held``: also return, per token (B, S), how many of its
+    assignments landed on held experts and were computed."""
     B, S, d = x.shape
     k = cfg.num_experts_per_tok
     E = cfg.resolved_padded_experts
     C = capacity_for(cfg, S)
 
     logits = jnp.einsum("bsd,de->bse", x, params["router"])
-    weights, expert_id, position, keep, aux = route(logits, k, C,
-                                                    cfg.num_experts)
+    weights, expert_id, position, keep, aux = route(
+        logits, k, C, cfg.num_experts, scoring=cfg.router_scoring,
+        bias=params.get("b_corr"), scale=cfg.routed_scaling_factor)
+    counts = (jnp.zeros(E, jnp.int32).at[expert_id.reshape(-1)].add(1)
+              if return_stats else None)
+    if cfg.expert_share:
+        # global expert -> its index in the held bank; absent experts map
+        # past the bank and their assignments are not dispatched
+        held = cfg.resolved_held_experts
+        local = np.full(E, len(held), np.int32)
+        local[list(held)] = np.arange(len(held))
+        expert_id = jnp.asarray(local)[expert_id]
+        keep = keep & (expert_id < len(held))
+        expert_id = jnp.minimum(expert_id, len(held) - 1)
+        E = len(held)
 
     # ---- dispatch: per-row scatter into (B, E, C, d) capacity buffers
     row = jnp.broadcast_to(jnp.arange(B)[:, None], (B, S * k))
@@ -206,7 +254,9 @@ def moe_mlp(params, x, cfg, shard=None, trust=None, return_stats=False):
     if cfg.num_shared_experts:
         sp = params["shared"]
         y = y + (jax.nn.silu(x @ sp["w_gate"]) * (x @ sp["w_up"])) @ sp["w_down"]
+    out = (y, aux * cfg.router_aux_weight)
     if return_stats:
-        counts = jnp.zeros(E, jnp.int32).at[eid.reshape(-1)].add(1)
-        return y, aux * cfg.router_aux_weight, counts
-    return y, aux * cfg.router_aux_weight
+        out += (counts,)
+    if return_held:
+        out += (keep.sum(-1, dtype=jnp.int32),)
+    return out

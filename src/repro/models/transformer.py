@@ -18,7 +18,8 @@ from repro.models import ssm as ssm_lib
 from repro.models.builder import Leaf, stack
 from repro.models.config import LayerSpec, ModelConfig
 from repro.models.layers import (attn_decl, attn_decode, attn_train,
-                                 mlp_decl, rmsnorm, swiglu)
+                                 mla_decl, mla_decode, mla_train, mlp_decl,
+                                 rmsnorm, swiglu)
 
 
 # ------------------------------------------------------------- decls
@@ -26,6 +27,8 @@ def layer_decl(spec: LayerSpec, cfg: ModelConfig) -> dict:
     decl = {"norm1": Leaf((cfg.d_model,), ("embed",), "zeros")}
     if spec.kind in ("attn", "local_attn"):
         decl["attn"] = attn_decl(cfg)
+    elif spec.kind == "mla":
+        decl["mla"] = mla_decl(cfg)
     elif spec.kind == "rglru":
         decl["rglru"] = rglru_lib.rglru_decl(cfg)
     elif spec.kind == "ssm":
@@ -50,6 +53,8 @@ def model_decl(cfg: ModelConfig) -> dict:
     }
     if cfg.remainder:
         decl["remainder"] = [layer_decl(s, cfg) for s in cfg.remainder]
+    if cfg.leading:
+        decl["leading"] = [layer_decl(s, cfg) for s in cfg.leading]
     if not cfg.tie_embeddings:
         decl["lm_head"] = Leaf((cfg.d_model, cfg.padded_vocab),
                                ("embed", "vocab"), scale=0.02)
@@ -79,6 +84,14 @@ def _layer_cache_decl(spec: LayerSpec, cfg: ModelConfig, batch: int,
         return _attn_cache_decl(cfg, batch, cache_len, 0)
     if spec.kind == "local_attn":
         return _attn_cache_decl(cfg, batch, cache_len, cfg.sliding_window)
+    if spec.kind == "mla":
+        # the latent (after its norm) and the rotated shared key of each
+        # position: what absorbed decode reads, no per-head keys/values
+        axes = ("batch", "cache_seq", None)
+        return {"ckv": Leaf((batch, cache_len, cfg.kv_lora_rank), axes,
+                            "zeros"),
+                "kpe": Leaf((batch, cache_len, cfg.qk_rope_head_dim), axes,
+                            "zeros")}
     if spec.kind == "rglru":
         inner = cfg.rglru_expand * cfg.d_model
         return {
@@ -105,19 +118,27 @@ def cache_decl(cfg: ModelConfig, batch: int, cache_len: int) -> dict:
     if cfg.remainder:
         decl["remainder"] = [_layer_cache_decl(s, cfg, batch, cache_len)
                              for s in cfg.remainder]
+    if cfg.leading:
+        decl["leading"] = [_layer_cache_decl(s, cfg, batch, cache_len)
+                           for s in cfg.leading]
     return decl
+
+
+# cache and parameter lists of layers outside the scanned stack
+UNSTACKED = ("leading", "remainder")
 
 
 # ------------------------------------------- block-granular KV paging
 def check_kv_pageable(cfg: ModelConfig) -> None:
     """KV paging (``repro.storage.kv``) addresses cache ROWS by absolute
-    position, which only the full-attention cache layout guarantees:
-    local_attn caches are capped ring windows and rglru/ssm carry
-    recurrent state that is not row-addressable.  Raises for those."""
-    for spec in list(cfg.block_pattern) + list(cfg.remainder):
-        if spec.kind != "attn":
+    position, which the full-attention and latent cache layouts
+    guarantee: local_attn caches are capped ring windows and rglru/ssm
+    carry recurrent state that is not row-addressable.  Raises for
+    those."""
+    for spec in cfg.all_layers:
+        if spec.kind not in ("attn", "mla"):
             raise ValueError(
-                f"kv_storage needs all-'attn' layers (row-addressable "
+                f"kv_storage needs all-'attn'/'mla' layers (row-addressable "
                 f"caches); config has a {spec.kind!r} layer")
 
 
@@ -125,13 +146,13 @@ def slice_kv_block(caches, slot: int, start: int, end: int) -> dict:
     """Copy one slot's cache rows [start, end) out of every layer's KV
     leaves, as host numpy arrays — the pytree a sealed KV block stores.
     Stacked block caches carry a leading layer axis (batch is axis 1);
-    remainder caches lead with batch."""
+    leading and remainder caches lead with batch."""
     block = {"blocks": jax.tree_util.tree_map(
         lambda a: np.asarray(a[:, slot, start:end]), caches["blocks"])}
-    if "remainder" in caches:
-        block["remainder"] = jax.tree_util.tree_map(
-            lambda a: np.asarray(a[slot, start:end]),
-            caches["remainder"])
+    for part in UNSTACKED:
+        if part in caches:
+            block[part] = jax.tree_util.tree_map(
+                lambda a: np.asarray(a[slot, start:end]), caches[part])
     return block
 
 
@@ -142,11 +163,12 @@ def restore_kv_block(caches, slot: int, start: int, block: dict) -> dict:
         lambda a, b: a.at[:, slot, start:start + b.shape[1]].set(
             jnp.asarray(b, a.dtype)),
         caches["blocks"], block["blocks"])}
-    if "remainder" in caches:
-        new["remainder"] = jax.tree_util.tree_map(
-            lambda a, b: a.at[slot, start:start + b.shape[0]].set(
-                jnp.asarray(b, a.dtype)),
-            caches["remainder"], block["remainder"])
+    for part in UNSTACKED:
+        if part in caches:
+            new[part] = jax.tree_util.tree_map(
+                lambda a, b: a.at[slot, start:start + b.shape[0]].set(
+                    jnp.asarray(b, a.dtype)),
+                caches[part], block[part])
     return new
 
 
@@ -179,6 +201,9 @@ def _layer_train(spec: LayerSpec, p, x, cfg, shard, trust, chunks):
         window = cfg.sliding_window if spec.kind == "local_attn" else 0
         y = attn_train(p["attn"], h, cfg, window=window, shard=shard,
                        q_chunk=chunks[0], kv_chunk=chunks[1])
+    elif spec.kind == "mla":
+        y = mla_train(p["mla"], h, cfg, q_chunk=chunks[0],
+                      kv_chunk=chunks[1])
     elif spec.kind == "rglru":
         y = rglru_lib.rglru_train(p["rglru"], h, cfg, shard=shard)
     elif spec.kind == "ssm":
@@ -220,6 +245,11 @@ def forward_train(params, tokens, cfg: ModelConfig, *, shard=None,
     if shard is not None:
         x = shard(x, "batch", "seq", "embed")
     chunks = (q_chunk, kv_chunk)
+    aux0 = jnp.zeros((), jnp.float32)
+    for i, spec in enumerate(cfg.leading):
+        x, a = _layer_train(spec, params["leading"][i], x, cfg, shard, trust,
+                            chunks)
+        aux0 = aux0 + a
 
     def body(carry, blk):
         x, aux = carry
@@ -231,8 +261,7 @@ def forward_train(params, tokens, cfg: ModelConfig, *, shard=None,
 
     if remat:
         body = jax.checkpoint(body)
-    (x, aux), _ = scan_or_unroll(body, (x, jnp.zeros((), jnp.float32)),
-                                 params["blocks"], unroll)
+    (x, aux), _ = scan_or_unroll(body, (x, aux0), params["blocks"], unroll)
     for i, spec in enumerate(cfg.remainder):
         x, a = _layer_train(spec, params["remainder"][i], x, cfg, shard,
                             trust, chunks)
@@ -256,13 +285,20 @@ def _layer_decode(spec: LayerSpec, p, cache, x, pos, cfg, shard,
                   expert_stats=False, write_mask=None, layer=None):
     """One layer of a decode step.  ``layer``: ``cache`` is the block
     stack's cache (leading layer axis) and this layer's state sits at
-    ``[layer]``; it is written there, so the stack updates in place."""
+    ``[layer]``; it is written there, so the stack updates in place.
+
+    Returns (x, new_cache, counts, held): ``counts`` the routed-token
+    counts with ``expert_stats``; ``held``, with an expert share, the
+    assignments that landed on held experts over the writing rows."""
     # named scopes land in each HLO op's ``op_name``, so the profiler's
     # device ops can be told apart by the layer part that made them
     attn = spec.kind in ("attn", "local_attn")
     with jax.named_scope("attention" if attn else spec.kind):
         h = rmsnorm(x, p["norm1"], cfg.norm_eps)
-        if attn:
+        if spec.kind == "mla":
+            y, new_cache = mla_decode(p["mla"], h, cache, pos, cfg,
+                                      mask=write_mask, layer=layer)
+        elif attn:
             # inactive slots (not decoding this step / past their prefill
             # length) write no KV row: the mask drops their update
             window = cfg.sliding_window if spec.kind == "local_attn" else 0
@@ -285,11 +321,17 @@ def _layer_decode(spec: LayerSpec, p, cache, x, pos, cfg, shard,
                     new_cache = jax.tree_util.tree_map(
                         lambda a, n: a.at[layer].set(n), cache, new_cache)
     x = x + y
-    counts = None
+    counts = held = None
     if spec.mlp != "none":
         with jax.named_scope("moe" if spec.mlp == "moe" else "mlp"):
             h = rmsnorm(x, p["norm2"], cfg.norm_eps)
-            if spec.mlp == "moe":
+            if spec.mlp == "moe" and cfg.expert_share:
+                y, _, held = moe_lib.moe_mlp(p["moe"], h, cfg, shard=shard,
+                                             return_held=True)
+                if write_mask is not None:
+                    held = held * write_mask[:, None]
+                held = held.sum()
+            elif spec.mlp == "moe":
                 if expert_stats:
                     y, _, counts = moe_lib.moe_mlp(p["moe"], h, cfg,
                                                    shard=shard,
@@ -300,7 +342,7 @@ def _layer_decode(spec: LayerSpec, p, cache, x, pos, cfg, shard,
                 y = swiglu(h, p["mlp"]["w_gate"], p["mlp"]["w_up"],
                            p["mlp"]["w_down"], shard=shard)
             x = x + y
-    return x, new_cache, counts
+    return x, new_cache, counts, held
 
 
 def forward_decode(params, caches, tokens, pos, cfg: ModelConfig, *,
@@ -311,8 +353,11 @@ def forward_decode(params, caches, tokens, pos, cfg: ModelConfig, *,
     vector (continuous batching: per-slot positions).  Returns
     (logits (B, 1, V), new_caches) — plus, with ``expert_stats``, the
     per-MoE-layer routed-token counts ``(num_moe_layers, E)`` in layer
-    order (scanned blocks first, then the remainder): the gate
-    statistics a serving edge feeds its expert cache/prefetcher with.
+    order (leading layers, scanned blocks, then the remainder): the
+    gate statistics a serving edge feeds its expert cache/prefetcher
+    with — plus, with an expert share (``cfg.held_experts``), the int32
+    number of assignments the writing rows routed to held experts, over
+    every MoE layer.
 
     ``write_mask`` (B,) bool: rows where it is False run the (padded)
     compute but leave their KV rows and recurrent state untouched — the
@@ -322,6 +367,24 @@ def forward_decode(params, caches, tokens, pos, cfg: ModelConfig, *,
     if shard is not None:
         x = shard(x, "batch", "seq", "embed")
     n_moe_blk = sum(1 for s in cfg.block_pattern if s.mlp == "moe")
+    counts, helds, new_caches = [], [], {}
+
+    def unstacked(part, x):
+        new_caches[part] = []
+        for i, spec in enumerate(getattr(cfg, part)):
+            x, nc, c, h = _layer_decode(spec, params[part][i],
+                                        caches[part][i], x, pos, cfg,
+                                        shard, expert_stats=expert_stats,
+                                        write_mask=write_mask)
+            new_caches[part].append(nc)
+            if c is not None:
+                counts.append(c[None])
+            if h is not None:
+                helds.append(h)
+        return x
+
+    if cfg.leading:
+        x = unstacked("leading", x)
 
     # the stacked block cache rides in the layer scan's carry and each
     # layer writes its rows at [layer, ...]: nothing rebuilds the stack
@@ -329,43 +392,42 @@ def forward_decode(params, caches, tokens, pos, cfg: ModelConfig, *,
         x, cch = carry
         blk, layer = inp
         cch = dict(cch)
-        cnts = []
+        cnts, hs = [], []
         for i, spec in enumerate(cfg.block_pattern):
-            x, cch[str(i)], c = _layer_decode(
+            x, cch[str(i)], c, h = _layer_decode(
                 spec, blk[str(i)], cch[str(i)], x, pos, cfg, shard,
                 expert_stats=expert_stats, write_mask=write_mask,
                 layer=layer)
             if c is not None:
                 cnts.append(c)
-        return (x, cch), (jnp.stack(cnts) if cnts else None)
+            if h is not None:
+                hs.append(h)
+        return (x, cch), (jnp.stack(cnts) if cnts else None,
+                          sum(hs) if hs else None)
 
-    (x, new_block_caches), blk_counts = scan_or_unroll(
+    (x, new_block_caches), (blk_counts, blk_held) = scan_or_unroll(
         body, (x, caches["blocks"]),
         (params["blocks"], jnp.arange(cfg.resolved_num_blocks)), unroll)
-    counts = ([blk_counts.reshape(-1, blk_counts.shape[-1])]
-              if expert_stats and n_moe_blk else [])
-    new_caches = {"blocks": new_block_caches}
+    if expert_stats and n_moe_blk:
+        counts.append(blk_counts.reshape(-1, blk_counts.shape[-1]))
+    if blk_held is not None:
+        helds.append(blk_held.sum())
+    new_caches["blocks"] = new_block_caches
     if cfg.remainder:
-        new_caches["remainder"] = []
-        for i, spec in enumerate(cfg.remainder):
-            x, nc, c = _layer_decode(spec, params["remainder"][i],
-                                     caches["remainder"][i], x, pos, cfg,
-                                     shard, expert_stats=expert_stats,
-                                     write_mask=write_mask)
-            new_caches["remainder"].append(nc)
-            if c is not None:
-                counts.append(c[None])
+        x = unstacked("remainder", x)
     with jax.named_scope("head"):
         x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
         head = (params["embed"].T if cfg.tie_embeddings
                 else params["lm_head"])
         logits = x @ head
+    out = (logits, new_caches)
     if expert_stats:
-        stats = (jnp.concatenate(counts, axis=0) if counts
+        out += ((jnp.concatenate(counts, axis=0) if counts
                  else jnp.zeros((0, max(cfg.resolved_padded_experts, 1)),
-                                jnp.int32))
-        return logits, new_caches, stats
-    return logits, new_caches
+                                jnp.int32)),)
+    if cfg.expert_share:
+        out += (sum(helds) if helds else jnp.zeros((), jnp.int32),)
+    return out
 
 
 def forward_serve_chunk(params, caches, tokens, start, pos, lengths, adv,
@@ -393,12 +455,13 @@ def forward_serve_chunk(params, caches, tokens, start, pos, lengths, adv,
     ``start``) — so a slot whose prompt ends inside the chunk hands off
     to generation mid-scan with no host round-trip.
 
-    Returns ``(out_tokens (C, B), new_caches[, stats])``:
+    Returns ``(out_tokens (C, B), new_caches[, stats][, held])``:
     ``out_tokens[t, b]`` is slot b's greedy next token after micro-step
     t — a generated token iff the slot was at or past its prompt
     boundary there (the host emits exactly those).  ``stats`` (with
     ``expert_stats``) sums the per-MoE-layer routed-token counts over
-    the chunk's micro-steps."""
+    the chunk's micro-steps; ``held`` (with an expert share) the
+    assignments routed to held experts, over layers and micro-steps."""
     B, C = tokens.shape
     pos = jnp.asarray(pos, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
@@ -408,23 +471,16 @@ def forward_serve_chunk(params, caches, tokens, start, pos, lengths, adv,
         caches, cur = carry
         tok, t = xt                              # (B,), scalar step index
         feed = jnp.where(t < lengths, tok, cur)
-        out = forward_decode(params, caches, feed[:, None], pos + t, cfg,
-                             shard=shard, unroll=unroll,
-                             expert_stats=expert_stats,
-                             write_mask=t < adv)
-        if expert_stats:
-            logits, caches, stats = out
-        else:
-            (logits, caches), stats = out, None
+        logits, caches, *extra = forward_decode(
+            params, caches, feed[:, None], pos + t, cfg, shard=shard,
+            unroll=unroll, expert_stats=expert_stats, write_mask=t < adv)
         nxt = logits[:, -1].argmax(axis=-1).astype(jnp.int32)
-        return (caches, nxt), (nxt, stats)
+        return (caches, nxt), (nxt, extra)
 
-    (caches, _), (outs, stats) = jax.lax.scan(
+    (caches, _), (outs, extra) = jax.lax.scan(
         micro, (caches, jnp.asarray(start, jnp.int32)),
         (tokens.T, jnp.arange(C)))
-    if expert_stats:
-        return outs, caches, stats.sum(axis=0)
-    return outs, caches
+    return (outs, caches, *(e.sum(axis=0) for e in extra))
 
 
 def lm_loss(logits, labels, mask=None):

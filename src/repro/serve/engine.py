@@ -89,8 +89,8 @@ class _EdgeExpertRuntime:
     """The engine's storage-layer sidecar: per-(MoE layer, expert) units
     registered once at startup, resolved per tick from the routing
     counts of that tick's prefill + decode steps (layer order identical
-    to ``transformer.forward_decode(expert_stats=True)``: scanned blocks
-    block-major, then the remainder)."""
+    to ``transformer.forward_decode(expert_stats=True)``: leading layers,
+    scanned blocks block-major, then the remainder)."""
 
     def __init__(self, cfg: ModelConfig, params, scfg: EdgeStorageConfig,
                  metrics: Optional[MetricsRegistry] = None):
@@ -133,6 +133,9 @@ class _EdgeExpertRuntime:
                                        {k: a[e] for k, a in routed.items()},
                                        0)
 
+        for i, spec in enumerate(self.cfg.leading):
+            if spec.mlp == "moe":
+                units_of(params["leading"][i]["moe"])
         nb = self.cfg.resolved_num_blocks
         blocks = params.get("blocks", {})
         for b in range(nb):
@@ -271,6 +274,23 @@ class SessionRecord:
             leaf_digests=list(self.leaves), claimed=claimed)
 
 
+def _zero_slots(caches, sel):
+    """``caches`` with the rows of the slots ``sel`` (B,) bool zeroed:
+    batch is axis 1 of the stacked block caches (a leading layer axis),
+    axis 0 of the leading and remainder layers' caches."""
+    def zero_rows(axis):
+        def f(a):
+            m = sel.reshape((1,) * axis + (-1,) + (1,) * (a.ndim - axis - 1))
+            return jnp.where(m, jnp.zeros((), a.dtype), a)
+        return f
+
+    new = {"blocks": jax.tree_util.tree_map(zero_rows(1), caches["blocks"])}
+    for part in tfm.UNSTACKED:
+        if part in caches:
+            new[part] = jax.tree_util.tree_map(zero_rows(0), caches[part])
+    return new
+
+
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, params, *, batch_slots: int = 4,
                  cache_len: int = 256, mesh=None,
@@ -278,7 +298,8 @@ class ServingEngine:
                  trust: Optional[TrustConfig] = None,
                  expert_storage: Optional[EdgeStorageConfig] = None,
                  kv_storage: Optional[KVStorageConfig] = None,
-                 obs: Optional[Observability] = None):
+                 obs: Optional[Observability] = None,
+                 donate_cache: bool = False):
         if cfg.is_encoder_decoder:
             raise NotImplementedError("engine drives decoder-only archs")
         self.cfg = cfg
@@ -296,11 +317,11 @@ class ServingEngine:
         # the prefill/decode steps' routing counts
         self.edge = None
         if expert_storage is not None:
-            has_moe = any(s.mlp == "moe"
-                          for s in list(cfg.block_pattern)
-                          + list(cfg.remainder))
-            if not has_moe:
+            if not any(s.mlp == "moe" for s in cfg.all_layers):
                 raise ValueError("expert_storage needs a MoE model")
+            if cfg.expert_share:
+                raise ValueError("expert_storage registers every expert; "
+                                 "this config holds a share of them")
             self.edge = _EdgeExpertRuntime(cfg, params, expert_storage,
                                            metrics=self.obs.metrics)
         # ---- KV paging through the chunked store: sealed prefix-CID
@@ -329,8 +350,18 @@ class ServingEngine:
         # (B, C) shapes per pow2 width bucket (jax.jit's shape cache) —
         # occupancy changes never recompile, and there is no per-token
         # Python dispatch inside a chunk
+        # ``donate_cache``: the step and the slot reset write the new
+        # cache into the old one's buffer, so the device never holds two
+        # (a long latent cache is most of the memory); the caller then
+        # keeps no reference to ``self.caches`` across either.  Off by
+        # default only while a caller still reuses the cache it passed
+        # in (a fault test that returns the step's input); every engine
+        # can donate once none does, and the option then goes
         self._step_fn = jax.jit(make_serve_chunk_step(
-            cfg, mesh, expert_stats=self.edge is not None))
+            cfg, mesh, expert_stats=self.edge is not None),
+            donate_argnums=(1,) if donate_cache else ())
+        self._reset_fn = jax.jit(_zero_slots,
+                                 donate_argnums=(0,) if donate_cache else ())
         self.tick = 0
         self.steps = 0                  # fused macro-step invocations
         self._done: Dict[int, List[int]] = {}
@@ -432,6 +463,7 @@ class ServingEngine:
                      "lengths": jnp.zeros(self.batch, jnp.int32),
                      "adv": jnp.zeros(self.batch, jnp.int32)}
             out = self._step_fn(self.params, self.caches, batch)
+            self.caches = out[1]
             jax.block_until_ready(out[0])
             n += 1
             if self.sched.policy != "continuous" \
@@ -471,22 +503,7 @@ class ServingEngine:
         whole-cache reset at batch refill."""
         sel = np.zeros(self.batch, bool)
         sel[idxs] = True
-        sel = jnp.asarray(sel)
-
-        def zero_rows(axis):
-            def f(a):
-                m = sel.reshape((1,) * axis + (-1,)
-                                + (1,) * (a.ndim - axis - 1))
-                return jnp.where(m, jnp.zeros((), a.dtype), a)
-            return f
-
-        # stacked block caches carry a leading layer axis: batch is axis 1
-        new = {"blocks": jax.tree_util.tree_map(zero_rows(1),
-                                                self.caches["blocks"])}
-        if "remainder" in self.caches:
-            new["remainder"] = jax.tree_util.tree_map(
-                zero_rows(0), self.caches["remainder"])
-        self.caches = new
+        self.caches = self._reset_fn(self.caches, jnp.asarray(sel))
 
     # ------------------------------------------------------- KV paging
     def _kv_template(self):
@@ -702,13 +719,18 @@ class ServingEngine:
                         else ("decode", "serve.decode_s"))
         with self.obs.span(name, metric=metric, tick=self.tick, width=C):
             with self.obs.span("launch"):
-                out = self._step_fn(self.params, self.caches, batch)
-                if self.edge is not None:
-                    outs, self.caches, stats = out
-                else:
-                    (outs, self.caches), stats = out, None
+                outs, self.caches, *extra = self._step_fn(
+                    self.params, self.caches, batch)
+                stats = extra.pop(0) if self.edge is not None else None
+                if self.cfg.expert_share:
+                    held = extra.pop(0)
             with self.obs.span("wait"):
                 outs = np.asarray(outs)      # (C, B) greedy next tokens
+            if self.cfg.expert_share:
+                # assignments routed to this device's experts, all MoE
+                # layers and micro-steps of the call
+                self.obs.metrics.counter("serve.held_assignments").add(
+                    int(held))
         host_s = now()
         if self.edge is not None and stats is not None:
             # resolve the chunk's activated experts through the edge

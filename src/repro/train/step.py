@@ -12,6 +12,7 @@ against ShapeDtypeStructs for the multi-pod dry-run.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -23,6 +24,20 @@ from repro.models import transformer as tfm
 from repro.models.config import ModelConfig
 from repro.optim import adamw
 from repro.sharding import Sharder, logical_rules
+
+
+def at_precision(cfg: ModelConfig, fn):
+    """``fn`` with its matrix products at ``cfg.matmul_precision``
+    (JAX's default precision, one bfloat16 pass on a TPU: ``fn`` as
+    it is)."""
+    if cfg.matmul_precision == "default":
+        return fn
+
+    @functools.wraps(fn)
+    def at(*args):
+        with jax.default_matmul_precision(cfg.matmul_precision):
+            return fn(*args)
+    return at
 
 
 def model_forward(params, batch, cfg: ModelConfig, shard=None, trust=None,
@@ -92,7 +107,7 @@ def make_train_step(cfg: ModelConfig, opt_cfg: adamw.AdamWConfig,
         metrics = {"loss": loss, "aux_loss": aux, **om}
         return params, opt_state, metrics
 
-    return train_step
+    return at_precision(cfg, train_step)
 
 
 def make_prefill_step(cfg: ModelConfig, mesh=None, unroll=False):
@@ -108,7 +123,7 @@ def make_prefill_step(cfg: ModelConfig, mesh=None, unroll=False):
                                         unroll=unroll)
         return logits[:, -1:].argmax(axis=-1)
 
-    return prefill_step
+    return at_precision(cfg, prefill_step)
 
 
 def make_decode_step(cfg: ModelConfig, mesh=None, unroll=False,
@@ -138,18 +153,17 @@ def make_decode_step(cfg: ModelConfig, mesh=None, unroll=False,
                                                    pos, cfg, shard=shard,
                                                    unroll=unroll)
         elif expert_stats:
-            logits, caches, stats = tfm.forward_decode(
+            logits, caches, stats, *_ = tfm.forward_decode(
                 params, caches, tokens, pos, cfg, shard=shard,
                 unroll=unroll, expert_stats=True, write_mask=active)
             return logits[:, -1].argmax(axis=-1), caches, stats
         else:
-            logits, caches = tfm.forward_decode(params, caches, tokens, pos,
-                                                cfg, shard=shard,
-                                                unroll=unroll,
-                                                write_mask=active)
+            logits, caches, *_ = tfm.forward_decode(
+                params, caches, tokens, pos, cfg, shard=shard,
+                unroll=unroll, write_mask=active)
         return logits[:, -1].argmax(axis=-1), caches
 
-    return decode_step
+    return at_precision(cfg, decode_step)
 
 
 def make_serve_chunk_step(cfg: ModelConfig, mesh=None, unroll=False,
@@ -181,7 +195,7 @@ def make_serve_chunk_step(cfg: ModelConfig, mesh=None, unroll=False,
             batch["lengths"], batch["adv"], cfg, shard=shard,
             unroll=unroll, expert_stats=expert_stats)
 
-    return serve_chunk_step
+    return at_precision(cfg, serve_chunk_step)
 
 
 def make_step(cfg: ModelConfig, kind: str, mesh=None,
