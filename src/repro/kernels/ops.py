@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.kernels import audit_gemm as _ag
 from repro.kernels import flash_attention as _fa
+from repro.kernels import mla_decode as _mla
 from repro.kernels import moe_gemm as _mg
 from repro.kernels import redundancy_vote as _rv
 from repro.kernels import rglru_scan as _rg
@@ -24,7 +25,7 @@ from repro.kernels import ref
 from repro.kernels.backend import default_backend
 
 __all__ = ["default_backend", "redundancy_vote", "moe_gemm", "audit_mlp",
-           "flash_attention", "ssd_scan", "rglru_scan"]
+           "flash_attention", "mla_decode", "ssd_scan", "rglru_scan"]
 
 
 # ------------------------------------------------------ redundancy vote
@@ -96,6 +97,40 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
         causal=causal, window=window, softcap=softcap,
         interpret=(backend == "interpret"))
     return jnp.moveaxis(out, 1, 2)
+
+
+# ------------------------------------------------------ absorbed MLA decode
+def mla_decode(q_lat, q_pe, k_pe, ckv, kpe, pos, slot, *, scale, layer=None,
+               backend: str | None = None):
+    """One query token per slot against the latent cache, absorbed:
+    softmax over rows ``0 .. min(pos, cap - 1)`` of
+    ``(q_lat . ckv^T + q_pe . kpe^T) * scale``, times ``ckv``, with this
+    step's rotary keys ``k_pe`` (B, r) first written at row ``slot[b]``
+    of each slot (``cap``: dropped).
+
+    q_lat: (B, H, c); q_pe: (B, H, r); pos, slot: (B,) int32.  ckv:
+    (B, cap, c), this step's latents already written, and kpe:
+    (B, cap, r), or with ``layer`` the stacked (L, B, cap, ·) leaves,
+    read at ``[layer]``.  The ref backend reads the whole cache; the
+    kernel reads each slot's live rows once, and takes the stacked
+    leaves whole (never a layer's slice, which would be a copy).
+    Returns (o_lat (B, H, c), kpe with the rows written)."""
+    backend = backend or default_backend()
+    if backend == "ref":
+        lead = () if layer is None else (layer,)
+        with jax.named_scope("kv_write"):
+            kpe = kpe.at[lead + (jnp.arange(k_pe.shape[0]), slot)].set(
+                k_pe, mode="drop")
+        one = (lambda a: a) if layer is None else (lambda a: a[layer])
+        return ref.mla_decode_ref(q_lat, q_pe, one(ckv), one(kpe), pos,
+                                  scale), kpe
+    stacked = layer is not None
+    if not stacked:                    # one layer: a leading axis of 1
+        ckv, kpe, layer = ckv[None], kpe[None], 0
+    o_lat, kpe = _mla.mla_decode(q_lat, q_pe, k_pe, ckv, kpe, pos, slot,
+                                 layer, scale=scale,
+                                 interpret=(backend == "interpret"))
+    return o_lat, (kpe if stacked else kpe[0])
 
 
 # ------------------------------------------------------ SSD scan

@@ -136,6 +136,23 @@ def attention_ref(q, k, v, *, causal=True, window=0, softcap=0.0):
     return out.reshape(B, Sq, H, D)
 
 
+# ------------------------------------------------- absorbed MLA decode
+def mla_decode_ref(q_lat, q_pe, ckv, kpe, pos, scale):
+    """Absorbed latent-attention core over the whole cache, rows past
+    ``pos`` masked.  q_lat: (B, H, c); q_pe: (B, H, r); ckv: (B, cap, c);
+    kpe: (B, cap, r); pos: (B,).  Returns o_lat (B, H, c)."""
+    cap = ckv.shape[-2]
+    s = (jnp.einsum("bhc,btc->bht", q_lat, ckv,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bhr,btr->bht", q_pe, kpe,
+                      preferred_element_type=jnp.float32))
+    s = s * scale
+    s = jnp.where(jnp.arange(cap)[None, None, :] <= pos[:, None, None], s,
+                  -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bht,btc->bhc", p.astype(ckv.dtype), ckv)
+
+
 # ------------------------------------------------- SSD scan
 def ssd_scan_ref(x, dt, A, Bmat, Cmat, state0):
     """Naive sequential SSM recurrence oracle.

@@ -9,6 +9,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
 from repro.models.builder import Leaf
 
 NEG_INF = -1e30
@@ -372,7 +373,10 @@ def mla_decode(params, x, cache, pos, cfg, *, mask=None, layer=None):
     (``q_nope . w_uk^T``) and the weighted sum of latents is expanded by
     ``w_uv`` after the softmax, so each cached position is read as its
     ``kv_lora_rank`` latent and its rotary key, never as per-head keys
-    and values.  Exactly the expanded form, reassociated.
+    and values.  Exactly the expanded form, reassociated.  The core
+    (the rotary key's write, scores, softmax, weighted latents) is
+    ``ops.mla_decode``: on a TPU one Pallas pass over each slot's live
+    rows.
 
     cache: {"ckv": (B, cap, kv_lora_rank), "kpe": (B, cap, rope)}, with
     a leading layer axis when ``layer`` is given (this layer writes at
@@ -387,25 +391,15 @@ def mla_decode(params, x, cache, pos, cfg, *, mask=None, layer=None):
     if mask is not None:
         slot = jnp.where(mask, slot, cap)          # out of range: dropped
     lead = () if layer is None else (layer,)
-    rows = jnp.arange(B)
     with jax.named_scope("kv_write"):
-        new_cache = {
-            "ckv": cache["ckv"].at[lead + (rows, slot)].set(c[:, 0],
-                                                           mode="drop"),
-            "kpe": cache["kpe"].at[lead + (rows, slot)].set(k_pe[:, 0],
-                                                           mode="drop")}
-    ckv = new_cache["ckv"] if layer is None else new_cache["ckv"][layer]
-    kpe = new_cache["kpe"] if layer is None else new_cache["kpe"][layer]
+        ckv = cache["ckv"].at[lead + (jnp.arange(B), slot)].set(
+            c[:, 0], mode="drop")
     w_uk, w_uv = _mla_up(params, cfg)
     q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0], w_uk)
-    s = (jnp.einsum("bhc,btc->bht", q_lat, ckv,
-                    preferred_element_type=jnp.float32)
-         + jnp.einsum("bhr,btr->bht", q_pe[:, 0], kpe,
-                      preferred_element_type=jnp.float32))
-    s = s * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
-    s = jnp.where(jnp.arange(cap)[None, None, :] <= pos[:, None, None], s,
-                  NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o_lat = jnp.einsum("bht,btc->bhc", p.astype(ckv.dtype), ckv)
+    o_lat, kpe = ops.mla_decode(
+        q_lat, q_pe[:, 0], k_pe[:, 0], ckv, cache["kpe"], pos, slot,
+        scale=(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5,
+        layer=layer)
+    new_cache = {"ckv": ckv, "kpe": kpe}
     o = jnp.einsum("bhc,chv->bhv", o_lat, w_uv)
     return o.reshape(B, 1, -1) @ params["wo"], new_cache

@@ -42,6 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import mla_decode as mla_kernel
+from repro.kernels.backend import default_backend
 from repro.models import transformer as tfm
 from repro.models.builder import materialize
 from repro.models.config import ModelConfig
@@ -274,6 +276,16 @@ class SessionRecord:
             leaf_digests=list(self.leaves), claimed=claimed)
 
 
+def latent_rows(pos: np.ndarray, C: int, cap: int, blk: int):
+    """Latent rows one ``mla`` layer's attention core covers over a
+    macro-step of ``C`` micro-steps from positions ``pos`` (B,): every
+    slot reads rows ``0 .. min(pos + t, cap - 1)`` at micro-step ``t``.
+    Returns (live, read): their sum, and the same in whole ``blk``-row
+    blocks, the unit the core reads in."""
+    rows = np.minimum(pos[None, :] + np.arange(C)[:, None], cap - 1) + 1
+    return int(rows.sum()), int((-(-rows // blk) * blk).sum())
+
+
 def _zero_slots(caches, sel):
     """``caches`` with the rows of the slots ``sel`` (B,) bool zeroed:
     batch is axis 1 of the stacked block caches (a leading layer axis),
@@ -362,6 +374,15 @@ class ServingEngine:
             donate_argnums=(1,) if donate_cache else ())
         self._reset_fn = jax.jit(_zero_slots,
                                  donate_argnums=(0,) if donate_cache else ())
+        # latent attention: how many layers read the latent cache, and the
+        # block they read it in (the reference reads every row)
+        mla = lambda specs: sum(s.kind == "mla" for s in specs)
+        self._mla_layers = (mla(cfg.leading) + mla(cfg.remainder)
+                            + mla(cfg.block_pattern)
+                            * cfg.resolved_num_blocks)
+        if self._mla_layers:
+            self._latent_blk = (cache_len if default_backend() == "ref"
+                                else mla_kernel.block_rows(cache_len))
         self.tick = 0
         self.steps = 0                  # fused macro-step invocations
         self._done: Dict[int, List[int]] = {}
@@ -712,7 +733,7 @@ class ServingEngine:
             return False
         self.steps += 1
         with self.obs.span("prepare", tick=self.tick):
-            C, need, adv, batch = self._prepare()
+            C, need, adv, pos, batch = self._prepare()
         prefill_now = (self.sched.policy == "continuous"
                        and bool((need > 0).any()))
         name, metric = (("prefill", "serve.prefill_s") if prefill_now
@@ -731,6 +752,15 @@ class ServingEngine:
                 # layers and micro-steps of the call
                 self.obs.metrics.counter("serve.held_assignments").add(
                     int(held))
+        if self._mla_layers:
+            # the latent rows the attention core covers (live) and reads
+            # (whole blocks), over micro-steps, slots and mla layers
+            live, read = latent_rows(pos, C, self.cache_len,
+                                     self._latent_blk)
+            self.obs.metrics.counter("serve.latent_rows_live").add(
+                self._mla_layers * live)
+            self.obs.metrics.counter("serve.latent_rows_read").add(
+                self._mla_layers * read)
         host_s = now()
         if self.edge is not None and stats is not None:
             # resolve the chunk's activated experts through the edge
@@ -761,7 +791,8 @@ class ServingEngine:
     def _prepare(self):
         """The macro-step's chunk width ``C`` and its inputs: per slot the
         prompt tokens it consumes (``need``) and the micro-steps it
-        advances (``adv``), and the device batch."""
+        advances (``adv``), their positions at its first micro-step
+        (``pos``), and the device batch."""
         m = self.obs.metrics
         m.histogram("serve.occupancy").observe(self.sched.occupancy())
         m.gauge("serve.queue_depth").set(self.sched.depth())
@@ -813,7 +844,7 @@ class ServingEngine:
         batch = {"tokens": jnp.asarray(tokens), "start": jnp.asarray(start),
                  "pos": jnp.asarray(pos), "lengths": jnp.asarray(need),
                  "adv": jnp.asarray(adv)}
-        return C, need, adv, batch
+        return C, need, adv, pos, batch
 
     def _replay(self, C: int, need: np.ndarray, adv: np.ndarray,
                 outs: np.ndarray, host_s: float) -> None:

@@ -233,3 +233,53 @@ def test_rglru_scan_matches_ref(B, S, C, seq_block):
     want = rglru_scan(a, b)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
+
+
+# ------------------------------------------------ absorbed MLA decode
+def _mla_case(key, layers, B, cap, H=4, c=128, r=64):
+    ks = jax.random.split(key, 5)
+    lead = (layers,) if layers else ()
+    return (jax.random.normal(ks[0], (B, H, c)) * 0.2,
+            jax.random.normal(ks[1], (B, H, r)) * 0.2,
+            jax.random.normal(ks[2], (B, r)),
+            jax.random.normal(ks[3], lead + (B, cap, c)),
+            jax.random.normal(ks[4], lead + (B, cap, r)))
+
+
+MLA_CAP = 4 * 512                       # four of the kernel's blocks
+MLA_CASES = {                           # positions; None: write dropped
+    "pos-0": [0] * 3,
+    "pos-blk-1": [511] * 3,
+    "pos-blk": [512] * 3,
+    "pos-mid": [1300] * 3,
+    "pos-cap-1": [MLA_CAP - 1] * 3,
+    "mixed-depths": [0, 511, 512, 1300, MLA_CAP - 1, MLA_CAP + 40],
+    "masked-slots": [(5, None), 700, (1300, None), 129],
+}
+
+
+@pytest.mark.parametrize("stack", [0, 3], ids=["unstacked", "stacked"])
+@pytest.mark.parametrize("case", sorted(MLA_CASES))
+def test_mla_decode_matches_ref(case, stack):
+    """The kernel writes each slot's rotary key at its row (none where
+    the write is dropped) and reads rows 0 .. min(pos, cap - 1) in
+    512-row blocks, a slot's last block masked past its position: it
+    matches the whole-cache reference at block edges, at the cache's
+    end, past it, with slots at different depths, with dropped writes,
+    and at ``layer`` > 0 of a stacked cache."""
+    from repro.kernels import ops
+    spec = [p if isinstance(p, tuple) else (p, True)
+            for p in MLA_CASES[case]]
+    pos = jnp.asarray([p for p, _ in spec], jnp.int32)
+    slot = jnp.asarray([min(p, MLA_CAP - 1) if keep else MLA_CAP
+                        for p, keep in spec], jnp.int32)
+    q_lat, q_pe, k_pe, ckv, kpe = _mla_case(jax.random.PRNGKey(len(case)),
+                                            stack, len(pos), MLA_CAP)
+    layer = stack - 1 if stack else None
+    got, want = (ops.mla_decode(q_lat, q_pe, k_pe, ckv, kpe, pos, slot,
+                                scale=0.3, layer=layer, backend=backend)
+                 for backend in ("interpret", "ref"))
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+

@@ -243,6 +243,49 @@ def test_engine_prefill_then_decode_matches_the_reference(model, verified):
         assert all(eng.records[r["id"]].finalized for r in reqs)
 
 
+@pytest.mark.parametrize("backend,blk", [("ref", 1024), ("interpret", 512)])
+def test_latent_row_counters_equal_their_closed_form(model, monkeypatch,
+                                                      backend, blk):
+    """Served through the kernel (interpreted) or the reference, the
+    tokens are the reference model's; ``serve.latent_rows_live`` sums,
+    over micro-steps, slots and mla layers, the rows 0 .. min(pos,
+    cap - 1) the attention core covers; ``serve.latent_rows_read`` the
+    same in whole blocks: the kernel's 512 rows, or the whole cache for
+    the reference, which reads every row."""
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", backend)
+    cfg, params = model
+    cap, slots = 1024, 3
+    eng = ServingEngine(cfg, params, batch_slots=slots, cache_len=cap,
+                        prefill_chunk=4)
+    seen = []
+    prepare = eng._prepare
+
+    def recording():
+        out = prepare()
+        seen.append((out[0], out[3].copy()))     # C, positions
+        return out
+
+    eng._prepare = recording
+    reqs = _requests(cfg, 4, 13)
+    reqs[0]["prompt"] = np.arange(600, dtype=np.int32) % cfg.vocab_size
+    eng.submit(reqs)
+    done = eng.run()
+    np.testing.assert_allclose(_served_gaps(params, reqs, done), 0.0,
+                               atol=1e-4)
+    live = read = 0
+    for C, pos in seen:
+        for t in range(C):
+            rows = np.minimum(pos + t, cap - 1) + 1
+            live += int(rows.sum())
+            read += int((np.ceil(rows / blk) * blk).sum())
+    metrics = eng.obs.metrics
+    assert metrics.value("serve.latent_rows_live") == cfg.num_layers * live
+    assert metrics.value("serve.latent_rows_read") == cfg.num_layers * read
+    whole = cap * slots * sum(C for C, _ in seen)
+    assert live < read <= whole
+    assert (read == whole) == (blk == cap)
+
+
 def test_donated_and_kept_caches_serve_the_same(model):
     cfg, params = model
     outs = []
