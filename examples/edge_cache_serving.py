@@ -3,37 +3,29 @@
 The paper's three-layer design keeps the expert bank in the *storage*
 layer — "the edge layer employs the activated experts downloaded from
 the storage layer" — and the chain records their CIDs.  This example
-runs both halves of that economy:
-
-1. A B-MoE system whose edge cache is smaller than the expert bank: the
-   executor resolves each round's bank through the cache (activated
-   experts pinned, LRU eviction under the byte budget), uploads only the
-   *changed* experts as new chunk-manifest versions, and the storage
-   report shows the transfer ledger — dedup savings, hit/miss traffic,
-   and modeled seconds on the deterministic cost model.
-2. A serving engine over a (smoke-sized) MoE transformer whose per-tick
-   routing counts drive the same ``ExpertCache``: cold ticks fetch,
-   warm ticks hit, and the EMA prefetcher warms the hottest experts.
+runs B-MoE rounds on an edge whose cache is smaller than the expert
+bank: the executor resolves each round's bank through the cache
+(activated experts pinned, LRU eviction under the byte budget), uploads
+only the *changed* experts as new chunk-manifest versions, and the
+storage report shows the transfer ledger — dedup savings, hit/miss
+traffic, and modeled seconds on the deterministic cost model.  The
+serving engine keeps its weights resident and resolves no experts
+through a cache.
 
 Run: PYTHONPATH=src python examples/edge_cache_serving.py
 """
-import dataclasses
 import json
 
 import numpy as np
 
-from repro.configs import get_config
 from repro.core.bmoe import BMoEConfig, BMoESystem
-from repro.data.synthetic import serving_requests
-from repro.serve.engine import EdgeStorageConfig, ServingEngine
-from repro.train.loop import init_model
 from repro.trust.protocol import TrustConfig
 
 rng = np.random.default_rng(0)
 x = rng.normal(size=(1024, 784)).astype(np.float32)
 y = rng.integers(0, 10, 1024)
 
-# ---- 1. training on a memory-constrained edge -------------------------
+# ---- training on a memory-constrained edge ----------------------------
 full_bank = BMoESystem(BMoEConfig(num_experts=8, num_edges=8, top_k=2,
                                   pow_difficulty=2, framework="optimistic",
                                   seed=0))
@@ -70,18 +62,3 @@ system.infer(x[:256], commit=False)
 print("half-bank budget: warm re-inference refetched",
       system.edge_cache.stats["fetched_bytes"] - before,
       "bytes (the evicted half)")
-
-# ---- 2. the serving engine's per-tick expert resolution ---------------
-mcfg = dataclasses.replace(get_config("qwen2-moe-a2.7b", smoke=True),
-                           padded_num_experts=0)
-params = init_model(mcfg, seed=0)
-engine = ServingEngine(mcfg, params, batch_slots=2, cache_len=48,
-                       expert_storage=EdgeStorageConfig(prefetch_topk=2))
-engine.submit(serving_requests(mcfg.vocab_size, 6, max_prompt=8,
-                               max_new=6, seed=0))
-done = engine.run()
-erep = engine.edge.report()
-print(f"served {len(done)} requests over {erep['ticks']} ticks:",
-      f"{erep['cache']['misses']} cold unit fetches,",
-      f"{erep['cache']['hits']} warm hits,",
-      f"{erep['cache']['prefetches']} prefetches")

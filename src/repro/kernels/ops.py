@@ -25,7 +25,8 @@ from repro.kernels import ref
 from repro.kernels.backend import default_backend
 
 __all__ = ["default_backend", "redundancy_vote", "moe_gemm", "audit_mlp",
-           "flash_attention", "mla_decode", "ssd_scan", "rglru_scan"]
+           "flash_attention", "mla_decode", "mla_read_block", "ssd_scan",
+           "rglru_scan"]
 
 
 # ------------------------------------------------------ redundancy vote
@@ -131,6 +132,13 @@ def mla_decode(q_lat, q_pe, k_pe, ckv, kpe, pos, slot, *, scale, layer=None,
                                  layer, scale=scale,
                                  interpret=(backend == "interpret"))
     return o_lat, (kpe if stacked else kpe[0])
+
+
+def mla_read_block(cap: int) -> int:
+    """The block of rows ``mla_decode`` reads a ``cap``-row latent cache
+    in on the default backend: the kernel's block, or the whole cache
+    under the ref backend, which reads every row."""
+    return cap if default_backend() == "ref" else _mla.block_rows(cap)
 
 
 # ------------------------------------------------------ SSD scan

@@ -1,9 +1,8 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
 Drives the continuous-batching serving engine (per-tick admit/evict,
-fused chunked prefill, greedy decode; ``--scheduling fixed`` for the
-legacy batch-synchronous baseline) over synthetic requests and reports
-throughput.
+fused chunked prefill, greedy decode) over synthetic requests and
+reports throughput.
 """
 from __future__ import annotations
 
@@ -13,7 +12,6 @@ from repro.configs import ARCH_IDS, get_config
 from repro.data.synthetic import serving_requests
 from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.engine import ServingEngine
-from repro.serve.scheduler import POLICIES
 from repro.train.loop import init_model
 
 
@@ -25,8 +23,6 @@ def main():
     ap.add_argument("--cache-len", type=int, default=128)
     ap.add_argument("--max-prompt", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=12)
-    ap.add_argument("--scheduling", choices=list(POLICIES),
-                    default="continuous")
     ap.add_argument("--prefill-chunk", type=int, default=16,
                     help="max prompt tokens fused per compiled step")
     ap.add_argument("--full", action="store_true",
@@ -41,7 +37,6 @@ def main():
     params = init_model(cfg, seed=0)
     engine = ServingEngine(cfg, params, batch_slots=args.slots,
                            cache_len=args.cache_len,
-                           scheduling=args.scheduling,
                            prefill_chunk=args.prefill_chunk)
     reqs = list(serving_requests(cfg.vocab_size, args.requests,
                                  max_prompt=args.max_prompt,

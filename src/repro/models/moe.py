@@ -182,8 +182,7 @@ def grouped_mlp(buf, w_gate, w_up, w_down, shard=None):
     return jnp.einsum("becf,efd->becd", h, w_down)
 
 
-def moe_mlp(params, x, cfg, shard=None, trust=None, return_stats=False,
-            return_held=False):
+def moe_mlp(params, x, cfg, shard=None, trust=None, return_held=False):
     """x: (B, S, d) -> (B, S, d), plus aux loss.
 
     With an expert share (``cfg.held_experts``) the router scores and
@@ -194,12 +193,6 @@ def moe_mlp(params, x, cfg, shard=None, trust=None, return_stats=False,
 
     ``trust``: optional hook applied to the routed-expert output buffer —
     the B-MoE redundancy + consensus vote.
-
-    ``return_stats``: also return the per-expert routed-token counts
-    ``(E,)`` (drops included — a dropped assignment still computed its
-    bucket, so its expert's parameters were needed).  This is the gate
-    statistic the serving engine's edge cache feeds its EMA prefetcher
-    with; default off so existing (y, aux) call sites are untouched.
 
     ``return_held``: also return, per token (B, S), how many of its
     assignments landed on held experts and were computed."""
@@ -212,8 +205,6 @@ def moe_mlp(params, x, cfg, shard=None, trust=None, return_stats=False,
     weights, expert_id, position, keep, aux = route(
         logits, k, C, cfg.num_experts, scoring=cfg.router_scoring,
         bias=params.get("b_corr"), scale=cfg.routed_scaling_factor)
-    counts = (jnp.zeros(E, jnp.int32).at[expert_id.reshape(-1)].add(1)
-              if return_stats else None)
     if cfg.expert_share:
         # global expert -> its index in the held bank; absent experts map
         # past the bank and their assignments are not dispatched
@@ -255,8 +246,6 @@ def moe_mlp(params, x, cfg, shard=None, trust=None, return_stats=False,
         sp = params["shared"]
         y = y + (jax.nn.silu(x @ sp["w_gate"]) * (x @ sp["w_up"])) @ sp["w_down"]
     out = (y, aux * cfg.router_aux_weight)
-    if return_stats:
-        out += (counts,)
     if return_held:
         out += (keep.sum(-1, dtype=jnp.int32),)
     return out

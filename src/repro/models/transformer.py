@@ -282,14 +282,14 @@ def _mask_rows(mask, new, old):
 
 
 def _layer_decode(spec: LayerSpec, p, cache, x, pos, cfg, shard,
-                  expert_stats=False, write_mask=None, layer=None):
+                  write_mask=None, layer=None):
     """One layer of a decode step.  ``layer``: ``cache`` is the block
     stack's cache (leading layer axis) and this layer's state sits at
     ``[layer]``; it is written there, so the stack updates in place.
 
-    Returns (x, new_cache, counts, held): ``counts`` the routed-token
-    counts with ``expert_stats``; ``held``, with an expert share, the
-    assignments that landed on held experts over the writing rows."""
+    Returns (x, new_cache, held): ``held``, with an expert share, the
+    assignments that landed on held experts over the writing rows, else
+    None."""
     # named scopes land in each HLO op's ``op_name``, so the profiler's
     # device ops can be told apart by the layer part that made them
     attn = spec.kind in ("attn", "local_attn")
@@ -321,7 +321,7 @@ def _layer_decode(spec: LayerSpec, p, cache, x, pos, cfg, shard,
                     new_cache = jax.tree_util.tree_map(
                         lambda a, n: a.at[layer].set(n), cache, new_cache)
     x = x + y
-    counts = held = None
+    held = None
     if spec.mlp != "none":
         with jax.named_scope("moe" if spec.mlp == "moe" else "mlp"):
             h = rmsnorm(x, p["norm2"], cfg.norm_eps)
@@ -332,32 +332,22 @@ def _layer_decode(spec: LayerSpec, p, cache, x, pos, cfg, shard,
                     held = held * write_mask[:, None]
                 held = held.sum()
             elif spec.mlp == "moe":
-                if expert_stats:
-                    y, _, counts = moe_lib.moe_mlp(p["moe"], h, cfg,
-                                                   shard=shard,
-                                                   return_stats=True)
-                else:
-                    y, _ = moe_lib.moe_mlp(p["moe"], h, cfg, shard=shard)
+                y, _ = moe_lib.moe_mlp(p["moe"], h, cfg, shard=shard)
             else:
                 y = swiglu(h, p["mlp"]["w_gate"], p["mlp"]["w_up"],
                            p["mlp"]["w_down"], shard=shard)
             x = x + y
-    return x, new_cache, counts, held
+    return x, new_cache, held
 
 
 def forward_decode(params, caches, tokens, pos, cfg: ModelConfig, *,
-                   shard=None, unroll=False, expert_stats=False,
-                   write_mask=None):
+                   shard=None, unroll=False, write_mask=None):
     """One decode step.  tokens: (B, 1); pos: int32 scalar (all rows at
     the same absolute position — the batch-synchronous path) or (B,)
     vector (continuous batching: per-slot positions).  Returns
-    (logits (B, 1, V), new_caches) — plus, with ``expert_stats``, the
-    per-MoE-layer routed-token counts ``(num_moe_layers, E)`` in layer
-    order (leading layers, scanned blocks, then the remainder): the
-    gate statistics a serving edge feeds its expert cache/prefetcher
-    with — plus, with an expert share (``cfg.held_experts``), the int32
-    number of assignments the writing rows routed to held experts, over
-    every MoE layer.
+    (logits (B, 1, V), new_caches) — plus, with an expert share
+    (``cfg.held_experts``), the int32 number of assignments the writing
+    rows routed to held experts, over every MoE layer.
 
     ``write_mask`` (B,) bool: rows where it is False run the (padded)
     compute but leave their KV rows and recurrent state untouched — the
@@ -366,19 +356,15 @@ def forward_decode(params, caches, tokens, pos, cfg: ModelConfig, *,
     x = jnp.take(params["embed"], tokens, axis=0)
     if shard is not None:
         x = shard(x, "batch", "seq", "embed")
-    n_moe_blk = sum(1 for s in cfg.block_pattern if s.mlp == "moe")
-    counts, helds, new_caches = [], [], {}
+    helds, new_caches = [], {}
 
     def unstacked(part, x):
         new_caches[part] = []
         for i, spec in enumerate(getattr(cfg, part)):
-            x, nc, c, h = _layer_decode(spec, params[part][i],
-                                        caches[part][i], x, pos, cfg,
-                                        shard, expert_stats=expert_stats,
-                                        write_mask=write_mask)
+            x, nc, h = _layer_decode(spec, params[part][i], caches[part][i],
+                                     x, pos, cfg, shard,
+                                     write_mask=write_mask)
             new_caches[part].append(nc)
-            if c is not None:
-                counts.append(c[None])
             if h is not None:
                 helds.append(h)
         return x
@@ -392,24 +378,18 @@ def forward_decode(params, caches, tokens, pos, cfg: ModelConfig, *,
         x, cch = carry
         blk, layer = inp
         cch = dict(cch)
-        cnts, hs = [], []
+        hs = []
         for i, spec in enumerate(cfg.block_pattern):
-            x, cch[str(i)], c, h = _layer_decode(
+            x, cch[str(i)], h = _layer_decode(
                 spec, blk[str(i)], cch[str(i)], x, pos, cfg, shard,
-                expert_stats=expert_stats, write_mask=write_mask,
-                layer=layer)
-            if c is not None:
-                cnts.append(c)
+                write_mask=write_mask, layer=layer)
             if h is not None:
                 hs.append(h)
-        return (x, cch), (jnp.stack(cnts) if cnts else None,
-                          sum(hs) if hs else None)
+        return (x, cch), (sum(hs) if hs else None)
 
-    (x, new_block_caches), (blk_counts, blk_held) = scan_or_unroll(
+    (x, new_block_caches), blk_held = scan_or_unroll(
         body, (x, caches["blocks"]),
         (params["blocks"], jnp.arange(cfg.resolved_num_blocks)), unroll)
-    if expert_stats and n_moe_blk:
-        counts.append(blk_counts.reshape(-1, blk_counts.shape[-1]))
     if blk_held is not None:
         helds.append(blk_held.sum())
     new_caches["blocks"] = new_block_caches
@@ -420,19 +400,14 @@ def forward_decode(params, caches, tokens, pos, cfg: ModelConfig, *,
         head = (params["embed"].T if cfg.tie_embeddings
                 else params["lm_head"])
         logits = x @ head
-    out = (logits, new_caches)
-    if expert_stats:
-        out += ((jnp.concatenate(counts, axis=0) if counts
-                 else jnp.zeros((0, max(cfg.resolved_padded_experts, 1)),
-                                jnp.int32)),)
     if cfg.expert_share:
-        out += (sum(helds) if helds else jnp.zeros((), jnp.int32),)
-    return out
+        return (logits, new_caches,
+                sum(helds) if helds else jnp.zeros((), jnp.int32))
+    return logits, new_caches
 
 
 def forward_serve_chunk(params, caches, tokens, start, pos, lengths, adv,
-                        cfg: ModelConfig, *, shard=None, unroll=False,
-                        expert_stats=False):
+                        cfg: ModelConfig, *, shard=None, unroll=False):
     """Fused serving macro-step: ``C`` engine ticks in ONE compiled call
     (a ``lax.scan`` of masked greedy decode micro-steps), advancing
     every batch slot one position per micro-step — prefilling slots
@@ -455,13 +430,12 @@ def forward_serve_chunk(params, caches, tokens, start, pos, lengths, adv,
     ``start``) — so a slot whose prompt ends inside the chunk hands off
     to generation mid-scan with no host round-trip.
 
-    Returns ``(out_tokens (C, B), new_caches[, stats][, held])``:
-    ``out_tokens[t, b]`` is slot b's greedy next token after micro-step
-    t — a generated token iff the slot was at or past its prompt
-    boundary there (the host emits exactly those).  ``stats`` (with
-    ``expert_stats``) sums the per-MoE-layer routed-token counts over
-    the chunk's micro-steps; ``held`` (with an expert share) the
-    assignments routed to held experts, over layers and micro-steps."""
+    Returns ``(out_tokens (C, B), new_caches)``, plus ``held`` with an
+    expert share: ``out_tokens[t, b]`` is slot b's greedy next token
+    after micro-step t — a generated token iff the slot was at or past
+    its prompt boundary there (the host emits exactly those); ``held``
+    the assignments routed to held experts, over layers and
+    micro-steps."""
     B, C = tokens.shape
     pos = jnp.asarray(pos, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
@@ -471,16 +445,16 @@ def forward_serve_chunk(params, caches, tokens, start, pos, lengths, adv,
         caches, cur = carry
         tok, t = xt                              # (B,), scalar step index
         feed = jnp.where(t < lengths, tok, cur)
-        logits, caches, *extra = forward_decode(
+        logits, caches, *held = forward_decode(
             params, caches, feed[:, None], pos + t, cfg, shard=shard,
-            unroll=unroll, expert_stats=expert_stats, write_mask=t < adv)
+            unroll=unroll, write_mask=t < adv)
         nxt = logits[:, -1].argmax(axis=-1).astype(jnp.int32)
-        return (caches, nxt), (nxt, extra)
+        return (caches, nxt), (nxt, held)
 
-    (caches, _), (outs, extra) = jax.lax.scan(
+    (caches, _), (outs, held) = jax.lax.scan(
         micro, (caches, jnp.asarray(start, jnp.int32)),
         (tokens.T, jnp.arange(C)))
-    return (outs, caches, *(e.sum(axis=0) for e in extra))
+    return (outs, caches, *(h.sum(axis=0) for h in held))
 
 
 def lm_loss(logits, labels, mask=None):

@@ -1,10 +1,10 @@
 """Continuous-batching serving engine with prefill/decode disaggregation.
 
 The engine serves requests (prompt + max_new_tokens) from a fixed set of
-batch slots, but — unlike the fixed-slot engine it replaces — the batch
-composition changes **every decode step**: finished requests are evicted
-and queued requests admitted each tick (``scheduler.SlotScheduler``),
-so a short request never waits for a long co-batched one to drain.
+batch slots whose composition changes **every decode step**: finished
+requests are evicted and queued requests admitted each tick
+(``scheduler.SlotScheduler``), so a short request never waits for a long
+co-batched one to drain.
 Prefill is disaggregated from decode inside ONE fused compiled step
 (``train.step.make_serve_chunk_step``): each call runs C engine ticks
 as a ``lax.scan`` in which prefilling slots consume up to C prompt
@@ -13,10 +13,7 @@ long prompt costs ceil(len/C) dispatches instead of len, and in-flight
 decode never stalls behind a token-by-token prompt feed.  Shapes are
 fixed per pow2 width bucket (per-slot positions, per-row write masks),
 so occupancy changes never recompile and there is no per-token Python
-dispatch inside a chunk.  ``scheduling="fixed"`` keeps the legacy
-batch-synchronous engine (admit only into a drained batch, prompts fed
-token-by-token through the decode step) as the benchmark baseline and
-trust-equivalence oracle.
+dispatch inside a chunk.
 
 Verified sessions (``trust=TrustConfig(...)``): the optimistic
 commit-challenge-audit protocol from ``repro.trust`` applied to
@@ -42,16 +39,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import mla_decode as mla_kernel
-from repro.kernels.backend import default_backend
+from repro.kernels import ops
 from repro.models import transformer as tfm
 from repro.models.builder import materialize
 from repro.models.config import ModelConfig
 from repro.obs import Observability, now
 from repro.obs.metrics import MetricsRegistry
 from repro.serve.scheduler import SlotScheduler, SlotState
-from repro.storage import (ExpertCache, ExpertStore, GateEMA,
-                           StorageNetwork)
+from repro.storage import ExpertCache, ExpertStore, StorageNetwork
 from repro.storage.kv import (KV_GENESIS, KVBlockStore, KVStorageConfig,
                               prefix_chain, prefix_cid)
 from repro.train.step import make_serve_chunk_step
@@ -62,156 +57,32 @@ from repro.trust.protocol import ChallengeWindow, TrustConfig
 from repro.trust.session import (SessionLeafRef, TickCommitment, commit_tick,
                                  verify_session_inclusion)
 
-__all__ = ["EdgeStorageConfig", "KVStorageConfig", "ServingEngine",
-           "SessionRecord", "SlotState"]
-
-
-@dataclasses.dataclass(frozen=True)
-class EdgeStorageConfig:
-    """Serving-edge expert storage (paper: the edge layer "employs the
-    activated experts downloaded from the storage layer").
-
-    With this config the engine registers every MoE layer's per-expert
-    weights as chunked content-addressed objects in a ``StorageNetwork``
-    and resolves, each tick, exactly the experts that tick routed to
-    through a bounded ``ExpertCache`` — cold ticks fetch, warm ticks hit
-    (serving params are frozen, so the manifests never go stale).  A
-    ``GateEMA`` over the per-tick routing counts drives prefetch of the
-    hottest experts into spare cache capacity."""
-    cache_bytes: Optional[int] = None      # None: unbounded
-    chunk_bytes: int = 1 << 15
-    prefetch_topk: int = 0
-    ema_decay: float = 0.8
-    num_nodes: int = 4
-    replication: int = 2
-    seed: int = 0
-
-
-class _EdgeExpertRuntime:
-    """The engine's storage-layer sidecar: per-(MoE layer, expert) units
-    registered once at startup, resolved per tick from the routing
-    counts of that tick's prefill + decode steps (layer order identical
-    to ``transformer.forward_decode(expert_stats=True)``: leading layers,
-    scanned blocks block-major, then the remainder)."""
-
-    def __init__(self, cfg: ModelConfig, params, scfg: EdgeStorageConfig,
-                 metrics: Optional[MetricsRegistry] = None):
-        self.cfg = cfg
-        self.scfg = scfg
-        self.network = StorageNetwork(num_nodes=scfg.num_nodes,
-                                      replication=scfg.replication,
-                                      seed=scfg.seed, metrics=metrics,
-                                      namespace="edge.network")
-        self.store = ExpertStore(self.network, chunk_bytes=scfg.chunk_bytes,
-                                 metrics=metrics, namespace="edge.store")
-        self.cache = ExpertCache(self.store, scfg.cache_bytes,
-                                 metrics=metrics, namespace="edge.cache")
-        self._like: List[Dict] = []           # per layer: one unit template
-        self._n_real = cfg.num_experts
-        self._register(params)
-        self.ema = GateEMA(len(self._like) * self._n_real,
-                           decay=scfg.ema_decay)
-        self.ticks = 0
-
-    @property
-    def num_layers(self) -> int:
-        return len(self._like)
-
-    def _unit_id(self, layer: int, expert: int) -> str:
-        return f"moe/{layer}/{expert}"
-
-    def _register(self, params) -> None:
-        """Chunk every (layer, expert) unit into the storage network
-        (version 0 — serving weights are frozen).  Router and shared-
-        expert weights stay gate-side resident: they run every tick."""
-        def units_of(moe_params):
-            # routed-expert weights only: (E, ...) leading expert axis
-            routed = {k: np.asarray(moe_params[k])
-                      for k in ("w_gate", "w_up", "w_down")}
-            layer = len(self._like)
-            self._like.append({k: a[0] for k, a in routed.items()})
-            for e in range(self._n_real):
-                self.store.put_version(self._unit_id(layer, e),
-                                       {k: a[e] for k, a in routed.items()},
-                                       0)
-
-        for i, spec in enumerate(self.cfg.leading):
-            if spec.mlp == "moe":
-                units_of(params["leading"][i]["moe"])
-        nb = self.cfg.resolved_num_blocks
-        blocks = params.get("blocks", {})
-        for b in range(nb):
-            for i, spec in enumerate(self.cfg.block_pattern):
-                if spec.mlp == "moe":
-                    units_of(jax.tree_util.tree_map(
-                        lambda a: a[b], blocks[str(i)]["moe"]))
-        for i, spec in enumerate(self.cfg.remainder):
-            if spec.mlp == "moe":
-                units_of(params["remainder"][i]["moe"])
-
-    def on_tick(self, stats: np.ndarray) -> None:
-        """Resolve the experts this tick activated (pinned during the
-        resolve), feed the EMA, and prefetch the hottest units into
-        spare capacity."""
-        stats = np.asarray(stats)[:, :self._n_real]
-        flat = stats.reshape(-1).astype(np.float64)
-        active = [(int(l), int(e)) for l, e in zip(*np.nonzero(stats))]
-        ids = [self._unit_id(l, e) for l, e in active]
-        self.cache.pin(ids)
-        try:
-            for (layer, e), oid in zip(active, ids):
-                self.cache.get(oid, 0, self._like[layer])
-            self.ema.update(flat)
-            if self.scfg.prefetch_topk:
-                ranked = [self._unit_id(u // self._n_real, u % self._n_real)
-                          for u in self.ema.ranking()[:self.scfg.prefetch_topk]]
-                self.cache.prefetch(
-                    ranked, 0,
-                    lambda oid: self._like[int(oid.split("/")[1])])
-        finally:
-            self.cache.unpin(ids)
-        self.ticks += 1
-
-    def report(self) -> Dict:
-        # same keys as pre-obs; with a registry the stats dicts are live
-        # views over the edge.{cache,store,network}.* metrics
-        return {"cache": dict(self.cache.stats),
-                "store": dict(self.store.stats),
-                "network": dict(self.network.stats),
-                "units": len(self._like) * self._n_real,
-                "ticks": self.ticks}
+__all__ = ["KVStorageConfig", "ServingEngine", "SessionRecord",
+           "SlotState"]
 
 
 class _KVRuntime:
-    """The engine's KV-paging sidecar: a ``KVBlockStore`` over either
-    its own storage network or — when the edge expert runtime is also
-    configured — the SAME store and cache as the expert weights, so KV
-    blocks and experts compete under one byte budget and one LRU
-    (experts are pinned while activated; cold KV evicts first).
+    """The engine's KV-paging sidecar: a ``KVBlockStore`` over its own
+    storage network, store and byte-budgeted cache.
 
     ``da_rate > 0`` adds data-availability challenges over the sealed
     KV chunks: the same corrupt-slash-repair / withhold-window-slash
     machinery that audits expert chunks (``repro.trust.da``)."""
 
-    def __init__(self, kcfg: KVStorageConfig, shared=None,
+    def __init__(self, kcfg: KVStorageConfig,
                  metrics: Optional[MetricsRegistry] = None):
         self.cfg = kcfg
         self.T = int(kcfg.block_tokens)
         if self.T < 1:
             raise ValueError(f"block_tokens {self.T} < 1")
-        if shared is not None:
-            self.store, self.cache = shared
-            self.network = self.store.network
-        else:
-            self.network = StorageNetwork(num_nodes=kcfg.num_nodes,
-                                          replication=kcfg.replication,
-                                          seed=kcfg.seed, metrics=metrics,
-                                          namespace="kv.network")
-            self.store = ExpertStore(self.network,
-                                     chunk_bytes=kcfg.chunk_bytes,
-                                     metrics=metrics, namespace="kv.store")
-            self.cache = ExpertCache(self.store, kcfg.cache_bytes,
-                                     metrics=metrics, namespace="kv.cache")
+        self.network = StorageNetwork(num_nodes=kcfg.num_nodes,
+                                      replication=kcfg.replication,
+                                      seed=kcfg.seed, metrics=metrics,
+                                      namespace="kv.network")
+        self.store = ExpertStore(self.network, chunk_bytes=kcfg.chunk_bytes,
+                                 metrics=metrics, namespace="kv.store")
+        self.cache = ExpertCache(self.store, kcfg.cache_bytes,
+                                 metrics=metrics, namespace="kv.cache")
         self.kv = KVBlockStore(self.store, self.cache, metrics=metrics)
         self.da = (DataAvailabilityAuditor(
             self.network, len(self.network.nodes), window=kcfg.da_window,
@@ -305,10 +176,8 @@ def _zero_slots(caches, sel):
 
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, params, *, batch_slots: int = 4,
-                 cache_len: int = 256, mesh=None,
-                 scheduling: str = "continuous", prefill_chunk: int = 16,
+                 cache_len: int = 256, mesh=None, prefill_chunk: int = 16,
                  trust: Optional[TrustConfig] = None,
-                 expert_storage: Optional[EdgeStorageConfig] = None,
                  kv_storage: Optional[KVStorageConfig] = None,
                  obs: Optional[Observability] = None,
                  donate_cache: bool = False):
@@ -323,23 +192,9 @@ class ServingEngine:
         self.caches = materialize(
             tfm.cache_decl(cfg, batch_slots, cache_len),
             jax.random.PRNGKey(0))
-        self.sched = SlotScheduler(batch_slots, policy=scheduling)
-        # ---- edge expert storage (MoE models): per-tick resolution of
-        # the activated experts through a bounded ExpertCache, fed by
-        # the prefill/decode steps' routing counts
-        self.edge = None
-        if expert_storage is not None:
-            if not any(s.mlp == "moe" for s in cfg.all_layers):
-                raise ValueError("expert_storage needs a MoE model")
-            if cfg.expert_share:
-                raise ValueError("expert_storage registers every expert; "
-                                 "this config holds a share of them")
-            self.edge = _EdgeExpertRuntime(cfg, params, expert_storage,
-                                           metrics=self.obs.metrics)
+        self.sched = SlotScheduler(batch_slots)
         # ---- KV paging through the chunked store: sealed prefix-CID
-        # blocks, warm-prefix restore on admission, page-out/resume.
-        # With BOTH runtimes on, KV shares the edge cache+store — the
-        # single-byte-budget competition between KV and expert weights.
+        # blocks, warm-prefix restore on admission, page-out/resume
         self.kvrt = None
         if kv_storage is not None:
             tfm.check_kv_pageable(cfg)
@@ -347,10 +202,7 @@ class ServingEngine:
                 raise ValueError(
                     f"block_tokens {kv_storage.block_tokens} cannot fit "
                     f"cache_len {cache_len} (need <= cache_len - 1)")
-            shared = ((self.edge.store, self.edge.cache)
-                      if self.edge is not None else None)
-            self.kvrt = _KVRuntime(kv_storage, shared=shared,
-                                   metrics=self.obs.metrics)
+            self.kvrt = _KVRuntime(kv_storage, metrics=self.obs.metrics)
         # per-slot prefix-chain cursor: {"prev": cid, "sealed": nblocks}
         self._kv_chain: List[Optional[Dict]] = [None] * batch_slots
         # paged-out requests awaiting readmission: rid -> resume state
@@ -369,20 +221,18 @@ class ServingEngine:
         # default only while a caller still reuses the cache it passed
         # in (a fault test that returns the step's input); every engine
         # can donate once none does, and the option then goes
-        self._step_fn = jax.jit(make_serve_chunk_step(
-            cfg, mesh, expert_stats=self.edge is not None),
-            donate_argnums=(1,) if donate_cache else ())
+        self._step_fn = jax.jit(make_serve_chunk_step(cfg, mesh),
+                                donate_argnums=(1,) if donate_cache else ())
         self._reset_fn = jax.jit(_zero_slots,
                                  donate_argnums=(0,) if donate_cache else ())
         # latent attention: how many layers read the latent cache, and the
-        # block they read it in (the reference reads every row)
+        # block they read it in
         mla = lambda specs: sum(s.kind == "mla" for s in specs)
         self._mla_layers = (mla(cfg.leading) + mla(cfg.remainder)
                             + mla(cfg.block_pattern)
                             * cfg.resolved_num_blocks)
         if self._mla_layers:
-            self._latent_blk = (cache_len if default_backend() == "ref"
-                                else mla_kernel.block_rows(cache_len))
+            self._latent_blk = ops.mla_read_block(cache_len)
         self.tick = 0
         self.steps = 0                  # fused macro-step invocations
         self._done: Dict[int, List[int]] = {}
@@ -419,10 +269,6 @@ class ServingEngine:
         self._open_sessions: set = set()
 
     # ------------------------------------------------------------- views
-    @property
-    def scheduling(self) -> str:
-        return self.sched.policy
-
     @property
     def slots(self) -> List[SlotState]:
         return self.sched.slots
@@ -472,10 +318,10 @@ class ServingEngine:
 
     def warmup(self) -> int:
         """Compile every fused-step width bucket up front (the pow2s up
-        to ``prefill_chunk``; just C=1 under the fixed policy) against
-        zero-advance dummy batches — ``adv=0`` masks every cache write,
-        so state is untouched — so no compile ever lands in a served
-        request's latency.  Returns the number of buckets compiled."""
+        to ``prefill_chunk``) against zero-advance dummy batches —
+        ``adv=0`` masks every cache write, so state is untouched — so no
+        compile ever lands in a served request's latency.  Returns the
+        number of buckets compiled."""
         w, n = 1, 0
         while True:
             batch = {"tokens": jnp.zeros((self.batch, w), jnp.int32),
@@ -487,8 +333,7 @@ class ServingEngine:
             self.caches = out[1]
             jax.block_until_ready(out[0])
             n += 1
-            if self.sched.policy != "continuous" \
-                    or w * 2 > self.prefill_chunk:
+            if w * 2 > self.prefill_chunk:
                 return n
             w *= 2
 
@@ -519,9 +364,7 @@ class ServingEngine:
                 self._open_sessions.add(rid)
 
     def _reset_slot_caches(self, idxs: List[int]) -> None:
-        """Zero the admitted slots' cache rows (KV + recurrent state) —
-        the continuous-batching replacement for the fixed-slot engine's
-        whole-cache reset at batch refill."""
+        """Zero the admitted slots' cache rows (KV + recurrent state)."""
         sel = np.zeros(self.batch, bool)
         sel[idxs] = True
         self.caches = self._reset_fn(self.caches, jnp.asarray(sel))
@@ -713,7 +556,7 @@ class ServingEngine:
         Spans under ``step``: ``admit`` (``reset-slots``), ``prepare``
         (chunk width, batch arrays), ``prefill``/``decode`` (``launch``:
         the compiled call until it returns; ``wait``: until its tokens
-        are on the host), ``edge-cache`` and ``kv-prefetch`` when on,
+        are on the host), ``kv-prefetch`` when on,
         ``replay`` (the per-tick host work: emit, ``commit``, finish,
         window expiry with its ``audit-drain``), ``kv-da`` when on."""
         with self.obs.span("step", metric="serve.tick_s", tick=self.tick):
@@ -734,24 +577,19 @@ class ServingEngine:
         self.steps += 1
         with self.obs.span("prepare", tick=self.tick):
             C, need, adv, pos, batch = self._prepare()
-        prefill_now = (self.sched.policy == "continuous"
-                       and bool((need > 0).any()))
-        name, metric = (("prefill", "serve.prefill_s") if prefill_now
+        name, metric = (("prefill", "serve.prefill_s") if (need > 0).any()
                         else ("decode", "serve.decode_s"))
         with self.obs.span(name, metric=metric, tick=self.tick, width=C):
             with self.obs.span("launch"):
-                outs, self.caches, *extra = self._step_fn(
+                outs, self.caches, *held = self._step_fn(
                     self.params, self.caches, batch)
-                stats = extra.pop(0) if self.edge is not None else None
-                if self.cfg.expert_share:
-                    held = extra.pop(0)
             with self.obs.span("wait"):
                 outs = np.asarray(outs)      # (C, B) greedy next tokens
-            if self.cfg.expert_share:
+            if held:
                 # assignments routed to this device's experts, all MoE
                 # layers and micro-steps of the call
                 self.obs.metrics.counter("serve.held_assignments").add(
-                    int(held))
+                    int(held[0]))
         if self._mla_layers:
             # the latent rows the attention core covers (live) and reads
             # (whole blocks), over micro-steps, slots and mla layers
@@ -762,11 +600,6 @@ class ServingEngine:
             self.obs.metrics.counter("serve.latent_rows_read").add(
                 self._mla_layers * read)
         host_s = now()
-        if self.edge is not None and stats is not None:
-            # resolve the chunk's activated experts through the edge
-            # cache (cold: chunk fetches; warm: hits) + EMA prefetch
-            with self.obs.span("edge-cache"):
-                self.edge.on_tick(np.asarray(stats))
         if self.kvrt is not None:
             # overlap with the chunk just dispatched: warm queued
             # requests' sealed prefix blocks into the cache
@@ -797,32 +630,21 @@ class ServingEngine:
         m.histogram("serve.occupancy").observe(self.sched.occupancy())
         m.gauge("serve.queue_depth").set(self.sched.depth())
         slots = self.sched.slots
-        continuous = self.sched.policy == "continuous"
 
-        # ---- chunk width C (continuous): the largest pow2 <= the
-        # busiest active slot's remaining work (prompt left + tokens
-        # left to generate, cache-bounded) — so no tick in the chunk is
-        # pure waste past everyone's completion — capped by
-        # prefill_chunk and every active slot's cache headroom.  The
-        # pow2 rounding bounds the compile set to log2(prefill_chunk)+1
-        # shape buckets.  The fixed baseline always runs C=1 with a
-        # 1-token prompt feed — the legacy batch-synchronous engine,
-        # bit for bit.
-        if continuous:
-            need = self.sched.prefill_lengths(self.prefill_chunk,
-                                              self.cache_len)
-            work = max((len(s.prompt) - s.cursor)
-                       + max(s.to_generate - len(s.generated), 0)
-                       for s in slots if s.active)
-            headroom = min(self.cache_len - 1 - s.pos
-                           for s in slots if s.active)
-            cmax = max(1, min(self.prefill_chunk, headroom, work))
-            C = 1 << (cmax.bit_length() - 1)      # round DOWN to pow2
-            need = np.minimum(need, C).astype(np.int32)
-        else:
-            C = 1
-            need = np.array([1 if s.prefilling else 0 for s in slots],
-                            np.int32)
+        # ---- chunk width C: the largest pow2 <= the busiest active
+        # slot's remaining work (prompt left + tokens left to generate,
+        # cache-bounded) — so no tick in the chunk is pure waste past
+        # everyone's completion — capped by prefill_chunk and every
+        # active slot's cache headroom.  The pow2 rounding bounds the
+        # compile set to log2(prefill_chunk)+1 shape buckets.
+        need = self.sched.prefill_lengths(self.prefill_chunk, self.cache_len)
+        work = max((len(s.prompt) - s.cursor)
+                   + max(s.to_generate - len(s.generated), 0)
+                   for s in slots if s.active)
+        headroom = min(self.cache_len - 1 - s.pos for s in slots if s.active)
+        cmax = max(1, min(self.prefill_chunk, headroom, work))
+        C = 1 << (cmax.bit_length() - 1)          # round DOWN to pow2
+        need = np.minimum(need, C).astype(np.int32)
 
         tokens = np.zeros((self.batch, C), np.int32)
         start = np.zeros(self.batch, np.int32)
@@ -924,8 +746,8 @@ class ServingEngine:
         throughput, wall-clock totals per phase, queue-wait and
         time-to-first-token percentiles (submission to admission, and
         to the first token on the host), slot occupancy, the
-        batched-commitment append counters, plus the edge storage
-        section when edge expert storage is on."""
+        batched-commitment append counters, plus the KV paging section
+        when KV paging is on."""
         m = self.obs.metrics
         out = {
             "ticks": self.tick,
@@ -943,8 +765,6 @@ class ServingEngine:
             "commit_appends": int(m.value("serve.commit.appends")),
             "commit_leaves": int(m.value("serve.commit.leaves")),
         }
-        if self.edge is not None:
-            out["edge"] = self.edge.report()
         if self.kvrt is not None:
             out["kv"] = self.kvrt.report()
         return out

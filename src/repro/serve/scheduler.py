@@ -1,19 +1,12 @@
 """Slot scheduler for the serving engine: request queue, slot lifecycle,
-and the admission policy.
+and admission.
 
-Two policies:
-
-- ``"continuous"`` (default): continuous batching.  Every tick, finished
-  slots are evicted and free slots admit from the queue immediately —
-  a request never waits for the rest of its batch to drain.  Admitted
-  requests enter the PREFILL phase (their prompt is chunk-consumed by
-  the engine's fused serve step while co-batched slots keep decoding)
-  and hand off to DECODE at the prompt boundary.
-- ``"fixed"``: the legacy fixed-slot baseline.  Requests are admitted
-  batch-synchronously — only when every slot is idle — and prompts are
-  fed token-by-token through the decode step (no chunk prefill), which
-  is exactly the engine this repo shipped before continuous batching.
-  Kept as the benchmark baseline and the trust-equivalence oracle.
+Continuous batching: every tick, finished slots are evicted and free
+slots admit from the queue immediately — a request never waits for the
+rest of its batch to drain.  Admitted requests enter the PREFILL phase
+(their prompt is chunk-consumed by the engine's fused serve step while
+co-batched slots keep decoding) and hand off to DECODE at the prompt
+boundary.
 
 A slot's request lifecycle (see ``src/repro/serve/README.md``):
 
@@ -27,11 +20,9 @@ from __future__ import annotations
 
 import dataclasses
 from collections import deque
-from typing import Deque, Dict, Iterable, List, Optional, Tuple
+from typing import Deque, Dict, Iterable, List, Tuple
 
 import numpy as np
-
-POLICIES = ("continuous", "fixed")
 
 
 @dataclasses.dataclass
@@ -64,14 +55,11 @@ class SlotScheduler:
     """Admission/eviction over a fixed set of batch slots.
 
     The engine drives it once per tick: ``admit(tick)`` fills free slots
-    from the queue (policy-dependent), the engine runs its prefill and
-    decode steps against ``slots``, and ``release(i)`` evicts a finished
-    slot so the *next* tick can admit into it."""
+    from the queue, the engine runs its prefill and decode steps against
+    ``slots``, and ``release(i)`` evicts a finished slot so the *next*
+    tick can admit into it."""
 
-    def __init__(self, num_slots: int, policy: str = "continuous"):
-        if policy not in POLICIES:
-            raise ValueError(f"policy {policy!r} not in {POLICIES}")
-        self.policy = policy
+    def __init__(self, num_slots: int):
         self.slots = [SlotState() for _ in range(num_slots)]
         self.queue: Deque[dict] = deque()
         self.submit_order: List[int] = []
@@ -94,11 +82,8 @@ class SlotScheduler:
     def admit(self, tick: int) -> List[Tuple[int, SlotState]]:
         """Admit queued requests into free slots; returns the newly
         filled ``(slot_index, slot)`` pairs (whose caches the engine must
-        reset).  Continuous policy admits whenever a slot is free; fixed
-        policy only refills a fully drained batch."""
+        reset), whenever a slot is free."""
         if not self.queue:
-            return []
-        if self.policy == "fixed" and any(s.active for s in self.slots):
             return []
         admitted = []
         for i, slot in enumerate(self.slots):
@@ -165,12 +150,10 @@ class SlotScheduler:
         """Requests waiting in the queue (not yet admitted)."""
         return len(self.queue)
 
-    def prefill_lengths(self, chunk: int, cache_len: int,
-                        fresh: Optional[set] = None) -> np.ndarray:
+    def prefill_lengths(self, chunk: int, cache_len: int) -> np.ndarray:
         """Per-slot prompt tokens to consume this tick, capped by the
-        chunk size, the remaining prompt, and the slot's cache headroom.
-        ``fresh``: slot indices admitted *this* tick (continuous policy
-        prefills them immediately); 0 for slots not prefilling."""
+        chunk size, the remaining prompt, and the slot's cache headroom;
+        0 for slots not prefilling."""
         n = np.zeros(self.num_slots, np.int32)
         for i, s in enumerate(self.slots):
             if not s.prefilling:

@@ -126,13 +126,8 @@ def make_prefill_step(cfg: ModelConfig, mesh=None, unroll=False):
     return at_precision(cfg, prefill_step)
 
 
-def make_decode_step(cfg: ModelConfig, mesh=None, unroll=False,
-                     expert_stats=False):
-    """``expert_stats=True`` (decoder-only MoE models) makes the step
-    also return the per-MoE-layer routed-token counts — what the serving
-    engine's edge expert cache resolves activated experts from.
-
-    The batch may carry ``pos`` as a scalar (every row at the same depth)
+def make_decode_step(cfg: ModelConfig, mesh=None, unroll=False):
+    """The batch may carry ``pos`` as a scalar (every row at the same depth)
     or a (B,) vector, and an optional (B,) bool ``active`` mask: inactive
     rows run the padded compute but leave their caches untouched — the
     fixed-shape contract continuous batching compiles once against."""
@@ -152,11 +147,6 @@ def make_decode_step(cfg: ModelConfig, mesh=None, unroll=False,
             logits, caches = encdec.forward_decode(params, caches, tokens,
                                                    pos, cfg, shard=shard,
                                                    unroll=unroll)
-        elif expert_stats:
-            logits, caches, stats, *_ = tfm.forward_decode(
-                params, caches, tokens, pos, cfg, shard=shard,
-                unroll=unroll, expert_stats=True, write_mask=active)
-            return logits[:, -1].argmax(axis=-1), caches, stats
         else:
             logits, caches, *_ = tfm.forward_decode(
                 params, caches, tokens, pos, cfg, shard=shard,
@@ -166,8 +156,7 @@ def make_decode_step(cfg: ModelConfig, mesh=None, unroll=False,
     return at_precision(cfg, decode_step)
 
 
-def make_serve_chunk_step(cfg: ModelConfig, mesh=None, unroll=False,
-                          expert_stats=False):
+def make_serve_chunk_step(cfg: ModelConfig, mesh=None, unroll=False):
     """Fused serving macro-step for the engine: one compiled call runs C
     engine ticks (``tfm.forward_serve_chunk`` — a ``lax.scan`` of masked
     greedy decode micro-steps) in which prefilling slots chunk-consume
@@ -179,8 +168,9 @@ def make_serve_chunk_step(cfg: ModelConfig, mesh=None, unroll=False,
     batch: ``tokens`` (B, C) int32, ``start`` (B,) int32 (last generated
     token per slot), ``pos`` (B,) int32, ``lengths`` (B,) int32 (prompt
     columns consumed), ``adv`` (B,) int32 (micro-steps the slot advances
-    at all; 0 = idle padding).  Returns (out_tokens (C, B),
-    caches[, stats])."""
+    at all; 0 = idle padding).  Returns (out_tokens (C, B), caches),
+    plus, with an expert share (``cfg.held_experts``), the assignments
+    routed to held experts."""
     from repro.sharding import use_fsdp
     if cfg.is_encoder_decoder:
         raise NotImplementedError("serve chunk drives decoder-only archs")
@@ -193,7 +183,7 @@ def make_serve_chunk_step(cfg: ModelConfig, mesh=None, unroll=False,
         return tfm.forward_serve_chunk(
             params, caches, batch["tokens"], batch["start"], batch["pos"],
             batch["lengths"], batch["adv"], cfg, shard=shard,
-            unroll=unroll, expert_stats=expert_stats)
+            unroll=unroll)
 
     return at_precision(cfg, serve_chunk_step)
 

@@ -1,8 +1,7 @@
 """Edge-cache accounting: LRU order under a byte budget, pinning,
-exact hit/miss/byte counters, EMA-driven prefetch, and cache-on vs
-cache-off bit-identity of the B-MoE system and the serving engine."""
-import dataclasses
-
+exact hit/miss/byte counters, EMA-driven prefetch, KV blocks and
+experts under one byte budget, and cache-on vs cache-off bit-identity
+of the B-MoE system."""
 import jax
 import numpy as np
 
@@ -238,38 +237,6 @@ def test_warm_cache_inference_fetches_nothing():
     assert s.edge_cache.stats["hits"] > hits
 
 
-# ----------------------------------------------------- serving engine
-def test_serving_engine_cache_on_off_identical_outputs():
-    from repro.configs import get_config
-    from repro.data.synthetic import serving_requests
-    from repro.serve.engine import EdgeStorageConfig, ServingEngine
-    from repro.train.loop import init_model
-    cfg = get_config("qwen2-moe-a2.7b", smoke=True)
-    cfg = dataclasses.replace(cfg, padded_num_experts=0)
-    params = init_model(cfg, seed=0)
-    reqs = list(serving_requests(cfg.vocab_size, 4, max_prompt=6,
-                                 max_new=4, seed=0))
-    plain = ServingEngine(cfg, params, batch_slots=2, cache_len=32)
-    plain.submit(reqs)
-    done_plain = plain.run()
-    edged = ServingEngine(cfg, params, batch_slots=2, cache_len=32,
-                          expert_storage=EdgeStorageConfig(prefetch_topk=2))
-    edged.submit(reqs)
-    done_edged = edged.run()
-    assert done_edged == done_plain
-    rep = edged.edge.report()
-    # cold start fetched each unit at most once; afterwards ticks hit
-    assert rep["cache"]["misses"] <= rep["units"]
-    assert rep["cache"]["hits"] > 0
-    assert rep["store"]["fetched_bytes"] <= \
-        rep["units"] * store_unit_bytes(rep)
-    assert rep["ticks"] > 0
-
-
-def store_unit_bytes(rep):
-    return rep["store"]["uploaded_bytes"] // max(rep["units"], 1)
-
-
 def test_kv_blocks_and_experts_compete_under_one_budget():
     """KV blocks resolved through the SAME cache as expert weights:
     under a tight budget the cold KV entries are evicted first while the
@@ -313,49 +280,6 @@ def test_kv_blocks_and_experts_compete_under_one_budget():
     assert cache.stats["evictions"] == 1
     assert cache.stats["evicted_bytes"] == kv_bytes
     assert cache.resident_bytes == nbytes + 2 * kv_bytes
-
-
-def test_serving_engine_shared_budget_kv_and_experts_identical_outputs():
-    """An engine running BOTH runtimes shares one store/cache (one byte
-    budget); a budget tight enough to force evictions changes nothing
-    about the streams."""
-    from repro.configs import get_config
-    from repro.data.synthetic import serving_requests
-    from repro.serve.engine import (EdgeStorageConfig, KVStorageConfig,
-                                    ServingEngine)
-    from repro.train.loop import init_model
-    cfg = get_config("qwen2-moe-a2.7b", smoke=True)
-    cfg = dataclasses.replace(cfg, padded_num_experts=0)
-    params = init_model(cfg, seed=0)
-    reqs = list(serving_requests(cfg.vocab_size, 4, max_prompt=6,
-                                 max_new=4, seed=0))
-
-    plain = ServingEngine(cfg, params, batch_slots=2, cache_len=32)
-    plain.submit([dict(r) for r in reqs])
-    done_plain = plain.run()
-
-    def shared(cache_bytes):
-        eng = ServingEngine(
-            cfg, params, batch_slots=2, cache_len=32,
-            expert_storage=EdgeStorageConfig(cache_bytes=cache_bytes),
-            kv_storage=KVStorageConfig(block_tokens=4))
-        assert eng.kvrt.cache is eng.edge.cache       # ONE budget
-        assert eng.kvrt.store is eng.edge.store
-        eng.submit([dict(r) for r in reqs])
-        return eng, eng.run()
-
-    eng, done = shared(cache_bytes=None)
-    assert done == done_plain
-    rep = eng.obs_report()["kv"]
-    assert rep["sealed_blocks"] > 0
-    # KV objects live in the same store namespace as the experts
-    assert any(o.startswith("kv/") for o in eng.edge.store.objects())
-    assert any(o.startswith("moe/") for o in eng.edge.store.objects())
-
-    tight, done_tight = shared(cache_bytes=eng.edge.cache.resident_bytes
-                               // 2)
-    assert done_tight == done_plain                   # thrash, not wrong
-    assert tight.edge.cache.stats["evictions"] > 0
 
 
 def test_gate_ema_ranking_deterministic_ties_by_id():

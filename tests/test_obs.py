@@ -321,20 +321,17 @@ def test_metrics_deterministic_and_blocks_unpolluted():
 
 
 def test_serving_engine_token_latency_report():
-    """Per-tick spans, one queue-wait and one time-to-first-token
-    observation per request on the obs clock, and the edge runtime's
-    legacy report keys."""
+    """Per-tick spans, and one queue-wait and one time-to-first-token
+    observation per request on the obs clock."""
     from repro.configs import get_config
     from repro.data.synthetic import serving_requests
-    from repro.serve.engine import EdgeStorageConfig, ServingEngine
+    from repro.serve.engine import ServingEngine
     from repro.train.loop import init_model
 
     cfg = get_config("qwen2-moe-a2.7b", smoke=True)
     params = init_model(cfg, seed=0)
     obs = Observability(enabled=True)
-    eng = ServingEngine(cfg, params, batch_slots=2, cache_len=32,
-                        expert_storage=EdgeStorageConfig(
-                            cache_bytes=1 << 20), obs=obs)
+    eng = ServingEngine(cfg, params, batch_slots=2, cache_len=32, obs=obs)
     reqs = list(serving_requests(cfg.vocab_size, 3, max_prompt=8,
                                  max_new=3, seed=0))
     eng.submit(reqs)
@@ -365,11 +362,6 @@ def test_serving_engine_token_latency_report():
     # entirely inside prefill chunks, so assert over the pair
     assert rep["tick_s"] >= rep["prefill_s"] + rep["decode_s"] > 0
     assert rep["tick_s"] >= rep["replay_s"] > 0
-    # the edge runtime's legacy report shape is unchanged
-    assert set(rep["edge"]) == {"cache", "store", "network", "units",
-                                "ticks"}
-    assert obs.metrics.value("edge.cache.hits") \
-        == rep["edge"]["cache"]["hits"]
     # one "step" span per fused macro-step (each covers C engine ticks;
     # the final drained step records a span too, before reporting no
     # work left)

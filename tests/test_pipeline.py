@@ -229,20 +229,20 @@ def test_serving_dependent_revocation(data):
     in-window neighbours — the serving analogue of the training chain
     rollback — while non-overlapping batches finalize untouched.
 
-    Batch-synchronous (fixed) scheduling: the pair structure this test
-    asserts — requests (2, 3) sharing no ticks with (0, 1) — only holds
-    when admission waits for the whole batch to drain.  Continuous
-    admission deliberately overlaps them (and chains the revocation
-    further); that is covered in tests/test_serving.py."""
+    The second pair is submitted only once the first is done (its
+    windows still open), so the pairs share no tick.  Continuous
+    admission into a running batch overlaps them instead (and chains
+    the revocation further); that is covered in tests/test_serving.py."""
     from repro.data.synthetic import serving_requests
     trust = TrustConfig(audit_rate=1.0, num_verifiers=1,
                         challenge_window=60)
-    eng = _tiny_engine(trust=trust, scheduling="fixed")
+    eng = _tiny_engine(trust=trust)
     reqs = list(serving_requests(eng.cfg.vocab_size, 4, max_prompt=6,
                                  max_new=4, seed=3))
-    eng.submit(reqs)
-    while len(eng._done) < 4 and eng.step():
-        pass
+    for n in (2, 4):
+        eng.submit(reqs[n - 2:n])
+        while len(eng._done) < n and eng.step():
+            pass
     assert eng.completed == {}                   # all windows still open
     pair1 = [reqs[0]["id"], reqs[1]["id"]]
     pair2 = [reqs[2]["id"], reqs[3]["id"]]

@@ -1,13 +1,20 @@
-"""Continuous-batching scheduler + engine edge cases, and the
-fixed-vs-continuous trust-verdict equivalence contract.
+"""Continuous-batching scheduler + engine edge cases, the engine's
+streams against greedy decoding by the full forward pass, the serve
+step's output contract, and the trust-verdict contract.
 
-The model-level tests run on the smallest dense config (smollm-360m
-smoke) — scheduling is architecture-agnostic, and the MoE paths are
-exercised by tests/test_substrate.py and tests/test_expert_cache.py.
+Most model-level tests run on the smallest dense config (smollm-360m
+smoke) — scheduling is architecture-agnostic; the reference test also
+serves a GQA model with QKV bias and a MoE model with shared experts.
 """
+import dataclasses
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import get_config
+from repro.models import transformer as tfm
 from repro.serve.scheduler import SlotScheduler, SlotState
 from repro.trust.commitments import MerkleTree
 from repro.trust.protocol import TrustConfig
@@ -21,21 +28,43 @@ def _req(rid, plen, new, vocab=64, seed=None):
             "max_new_tokens": new}
 
 
-def _engine(batch_slots=2, cache_len=64, **kw):
-    from repro.configs import get_config
-    from repro.serve.engine import ServingEngine
+def _model(arch="smollm-360m", **overrides):
     from repro.train.loop import init_model
-    cfg = get_config("smollm-360m", smoke=True)
-    params = init_model(cfg, seed=0)
+    cfg = dataclasses.replace(get_config(arch, smoke=True), **overrides)
+    return cfg, init_model(cfg, seed=0)
+
+
+def _engine(batch_slots=2, cache_len=64, **kw):
+    from repro.serve.engine import ServingEngine
+    cfg, params = _model()
     return ServingEngine(cfg, params, batch_slots=batch_slots,
                          cache_len=cache_len, **kw)
+
+
+def _greedy_reference(cfg, params, prompt, n):
+    """The plain reference: ``n`` tokens of greedy decoding by the full
+    forward pass — each the argmax at the last position of
+    ``forward_train`` over the prompt and the tokens so far.  MoE layers
+    get a capacity that every assignment fits (E / k): a one-token
+    decode step never drops one, and a full pass at the configured
+    factor could."""
+    if cfg.num_experts:
+        cfg = dataclasses.replace(
+            cfg, capacity_factor=cfg.num_experts / cfg.num_experts_per_tok)
+    toks = [int(t) for t in prompt]
+    for _ in range(n):
+        logits, _ = tfm.forward_train(params, jnp.asarray([toks], jnp.int32),
+                                      cfg, remat=False, q_chunk=8,
+                                      kv_chunk=8)
+        toks.append(int(jnp.argmax(logits[0, -1])))
+    return toks[len(prompt):]
 
 
 # ----------------------------------------------------------- scheduler
 def test_scheduler_admission_under_full_batch():
     """A full batch admits nothing; eviction frees exactly one slot and
     the head of the queue takes it (FIFO) on the next admit."""
-    sched = SlotScheduler(2, policy="continuous")
+    sched = SlotScheduler(2)
     sched.submit([_req(0, 4, 2), _req(1, 4, 2), _req(2, 4, 2),
                   _req(3, 4, 2)], tick=0)
     assert len(sched.admit(0)) == 2              # slots filled, 2 queued
@@ -50,21 +79,10 @@ def test_scheduler_admission_under_full_batch():
     assert sched.occupancy() == 1.0
 
 
-def test_scheduler_fixed_policy_waits_for_drain():
-    """The fixed baseline only refills a fully drained batch."""
-    sched = SlotScheduler(2, policy="fixed")
-    sched.submit([_req(0, 4, 2), _req(1, 4, 2), _req(2, 4, 2)], tick=0)
-    assert len(sched.admit(0)) == 2
-    sched.release(0, tick=3)
-    assert sched.admit(4) == []                  # slot 1 still active
-    sched.release(1, tick=6)
-    assert [s.request_id for _, s in sched.admit(7)] == [2]
-
-
 def test_scheduler_prefill_lengths_caps():
     """Chunk consumption is capped by chunk size, remaining prompt, and
     cache headroom — and is 0 for decoding/idle slots."""
-    sched = SlotScheduler(3, policy="continuous")
+    sched = SlotScheduler(3)
     sched.slots[0] = SlotState(request_id=0, pos=0,
                                prompt=np.zeros(20, np.int32), cursor=0,
                                to_generate=1)
@@ -77,11 +95,6 @@ def test_scheduler_prefill_lengths_caps():
                                to_generate=3)
     n = sched.prefill_lengths(chunk=16, cache_len=10)
     assert n.tolist() == [9, 2, 0]     # headroom 9; remaining prompt 2; 0
-
-
-def test_scheduler_rejects_unknown_policy():
-    with pytest.raises(ValueError):
-        SlotScheduler(2, policy="clairvoyant")
 
 
 # ------------------------------------------------------- tick commitments
@@ -111,8 +124,8 @@ def test_commit_tick_rejects_bad_entries():
 # ----------------------------------------------------------- the engine
 def test_engine_warmup_compiles_every_bucket_without_state_change():
     """``warmup()`` visits every pow2 width bucket up to prefill_chunk
-    (just C=1 under the fixed policy) and leaves generation unchanged —
-    a warmed engine produces the same stream as a cold one."""
+    and leaves generation unchanged — a warmed engine produces the same
+    stream as a cold one."""
     cold = _engine(prefill_chunk=8)
     warm = _engine(prefill_chunk=8)
     assert warm.warmup() == 4          # C in {1, 2, 4, 8}
@@ -120,9 +133,6 @@ def test_engine_warmup_compiles_every_bucket_without_state_change():
     reqs = [_req(0, 11, 4), _req(1, 3, 4)]
     assert warm.run() == {} and (warm.submit(reqs) or warm.run()) \
         == (cold.submit(reqs) or cold.run())
-
-    fixed = _engine(scheduling="fixed")
-    assert fixed.warmup() == 1         # fixed policy only ever runs C=1
 
 
 def test_engine_zero_max_new_tokens():
@@ -161,38 +171,83 @@ def test_engine_eviction_of_revoked_session_mid_window():
 
 
 def test_engine_no_queue_starvation_under_long_prompts():
-    """Chunked prefill + continuous admission: short requests behind a
-    long-prompt request finish in strictly fewer ticks than the
-    batch-synchronous baseline, and the long prompt costs ~len/chunk
-    prefill dispatches instead of len decode ticks."""
+    """Chunked prefill + continuous admission: a request queued behind a
+    long-prompt request gets its first token before the long request
+    finishes, every stream is the plain reference's, and the long
+    prompt costs ~len/chunk prefill dispatches instead of len decode
+    ticks."""
     reqs = [_req(0, 48, 2), _req(1, 4, 2), _req(2, 4, 2), _req(3, 4, 2)]
-
-    def run(scheduling):
-        eng = _engine(scheduling=scheduling, prefill_chunk=16)
-        eng.submit([dict(r, prompt=r["prompt"].copy()) for r in reqs])
-        done = eng.run()
-        return eng, done
-
-    cont_eng, cont_done = run("continuous")
-    fix_eng, fix_done = run("fixed")
-    # identical outputs per request — scheduling must not change tokens
-    assert set(cont_done) == set(fix_done) == {0, 1, 2, 3}
-    for rid in fix_done:
-        assert cont_done[rid] == fix_done[rid], rid
-    # continuous drains the workload in strictly fewer ticks: the long
-    # prompt chunks through in ~len/16 fused dispatches while the short
-    # requests stream through the other slot back-to-back
-    assert cont_eng.tick < fix_eng.tick
-    # and a QUEUED request is admitted the moment a slot frees instead
-    # of waiting for the long prompt's whole batch to drain — its first
-    # token lands dozens of ticks earlier than the fixed baseline's
-    cont_first = cont_eng.request_meta[2]["first_token_tick"]
-    fix_first = fix_eng.request_meta[2]["first_token_tick"]
-    assert cont_first < fix_first
+    eng = _engine(prefill_chunk=16)
+    eng.submit([dict(r, prompt=r["prompt"].copy()) for r in reqs])
+    done = eng.run()
+    assert set(done) == {0, 1, 2, 3}
+    for r in reqs:
+        assert done[r["id"]] == _greedy_reference(
+            eng.cfg, eng.params, r["prompt"], r["max_new_tokens"]), r["id"]
+    # request 2 waited only for request 1's slot, not for the long
+    # prompt's whole batch to drain
+    meta = eng.request_meta
+    assert meta[2]["first_token_tick"] < meta[0]["finished_tick"]
     # the long prompt costs ceil(48/16)=3 fused dispatches for its
     # prefill instead of 48 single-token calls: total compiled-call
     # count stays far below the tick count
-    assert cont_eng.steps < cont_eng.tick
+    assert eng.steps < eng.tick
+
+
+@pytest.mark.parametrize("arch,overrides", [
+    ("smollm-360m", {}),                          # dense
+    ("qwen2.5-3b", {}),                           # GQA with QKV bias
+    ("qwen2-moe-a2.7b", {"padded_num_experts": 0}),   # MoE, shared experts
+])
+def test_engine_streams_equal_greedy_reference(arch, overrides):
+    """Staggered, co-batched requests — more requests than slots,
+    prompts longer than ``prefill_chunk`` mixed with short ones, some
+    submitted while others are mid-stream — each get exactly the tokens
+    of greedy decoding by the full forward pass."""
+    from repro.serve.engine import ServingEngine
+    cfg, params = _model(arch, **overrides)
+    eng = ServingEngine(cfg, params, batch_slots=2, cache_len=64,
+                        prefill_chunk=4)
+    reqs = [_req(0, 11, 4), _req(1, 3, 5), _req(2, 9, 3), _req(3, 2, 4),
+            _req(4, 6, 5)]
+    eng.submit(reqs[:3])
+    for _ in range(3):
+        eng.step()
+    eng.submit(reqs[3:])
+    done = eng.run()
+    assert set(done) == {r["id"] for r in reqs}
+    for r in reqs:
+        assert done[r["id"]] == _greedy_reference(
+            cfg, params, r["prompt"], r["max_new_tokens"]), r["id"]
+
+
+@pytest.mark.parametrize("share", [False, True])
+def test_serve_step_output_contract(share):
+    """``make_serve_chunk_step`` returns ``(tokens (C, B), caches)``, and
+    with an expert share ``(tokens, caches, held)``: the arity callers
+    that wrap the step rely on."""
+    from repro.models.builder import materialize
+    from repro.train.step import make_serve_chunk_step
+    cfg, params = _model("qwen2-moe-a2.7b", padded_num_experts=0,
+                         held_experts=(0, 2) if share else ())
+    B, C = 2, 4
+    caches = materialize(tfm.cache_decl(cfg, B, 16), jax.random.PRNGKey(0))
+    batch = {"tokens": jnp.ones((B, C), jnp.int32),
+             "start": jnp.zeros(B, jnp.int32),
+             "pos": jnp.zeros(B, jnp.int32),
+             "lengths": jnp.full(B, C, jnp.int32),
+             "adv": jnp.full(B, C, jnp.int32)}
+    out = jax.jit(make_serve_chunk_step(cfg))(params, caches, batch)
+    assert len(out) == (3 if share else 2)
+    assert out[0].shape == (C, B)
+    assert jax.tree_util.tree_structure(out[1]) \
+        == jax.tree_util.tree_structure(caches)
+    if share:
+        # assignments routed to held experts, over layers and micro-steps
+        moe_layers = sum(s.mlp == "moe" for s in cfg.all_layers)
+        held = int(out[2])
+        assert out[2].shape == () and \
+            0 < held <= C * B * cfg.num_experts_per_tok * moe_layers
 
 
 def test_engine_batched_tick_commitments():
@@ -244,23 +299,22 @@ def test_engine_audit_catches_tick_inclusion_break():
 
 
 def test_fixed_vs_continuous_trust_verdict_equivalence():
-    """The trust contract of the rebuild: on the same seeded request
-    trace, continuous scheduling and the fixed baseline produce the
-    same per-request verdict map — every honest request finalizes in
-    both, and tampering the same request revokes it in both."""
+    """The trust contract of continuous batching on a seeded request
+    trace: on the honest trace every session's audit is clean and every
+    session finalizes; with request 2's stream altered, request 2 is
+    revoked, and every session that shares no tick with request 2 keeps
+    its honest verdict."""
     from repro.data.synthetic import serving_requests
 
-    def run(scheduling, tamper_rid=None):
+    def run(tamper_rid=None):
         # window wide enough that no session finalizes before the whole
-        # trace is served — the tamper must land in-window in BOTH
-        # schedules (fixed drains its first batch much earlier)
+        # trace is served — the tamper must land in-window
         trust = TrustConfig(audit_rate=1.0, num_verifiers=1,
                             challenge_window=120)
-        eng = _engine(trust=trust, scheduling=scheduling)
-        from repro.configs import get_config
-        cfg = get_config("smollm-360m", smoke=True)
-        eng.submit(list(serving_requests(cfg.vocab_size, 5, max_prompt=10,
-                                         max_new=5, seed=11)))
+        eng = _engine(trust=trust)
+        eng.submit(list(serving_requests(eng.cfg.vocab_size, 5,
+                                         max_prompt=10, max_new=5,
+                                         seed=11)))
         while eng._done.keys() != {0, 1, 2, 3, 4} and eng.step():
             pass
         if tamper_rid is not None:
@@ -270,18 +324,19 @@ def test_fixed_vs_continuous_trust_verdict_equivalence():
         verdicts = {rid: ("revoked" if eng.records[rid].revoked
                           else "finalized" if rid in done else "open")
                     for rid in eng.records}
-        return done, verdicts
+        return eng, verdicts
 
-    cont_done, cont_v = run("continuous")
-    fix_done, fix_v = run("fixed")
-    assert cont_done == fix_done                 # same tokens, greedy
-    assert cont_v == fix_v == {rid: "finalized" for rid in range(5)}
-    # tamper the same session post-run in both schedules: revoked in both
-    _, cont_v2 = run("continuous", tamper_rid=2)
-    _, fix_v2 = run("fixed", tamper_rid=2)
-    assert cont_v2[2] == fix_v2[2] == "revoked"
-    assert all(v != "finalized" for rid, v in cont_v2.items()
-               if rid == 2)
+    honest, v = run()
+    assert v == {rid: "finalized" for rid in range(5)}
+    assert all(rec.audited for rec in honest.records.values())
+    assert all(not a["mismatches"] and not a["revoked"]
+               for a in honest.audit_all())
+    tampered, v2 = run(tamper_rid=2)
+    assert v2[2] == "revoked"
+    rec2 = tampered.records[2]
+    apart = [rid for rid, rec in tampered.records.items()
+             if rid != 2 and not tampered._overlaps(rec, rec2)]
+    assert apart and all(v2[rid] == v[rid] for rid in apart)
 
 
 # ------------------------------------------------------------ KV paging
@@ -456,7 +511,7 @@ def test_engine_continuous_dependent_revocation_chains_through_admission():
     """Continuous admission deliberately widens the dependent-revocation
     blast radius: a request admitted into a freed slot shares decode
     ticks with the still-running stream, so fraud on the long stream
-    voids it too (the fixed-policy pair structure is covered in
+    voids it too (pairs that share no tick are covered in
     tests/test_pipeline.py)."""
     trust = TrustConfig(audit_rate=1.0, num_verifiers=1,
                         challenge_window=80)
