@@ -158,12 +158,38 @@ def decode_attention(q, k_cache, v_cache, pos, *, window=0, softcap=0.0):
     return out.reshape(B, 1, H, D)
 
 
+# ------------------------------------------------------ matrix products
+def product(x, w, spec=None):
+    """``x @ w``, or ``jnp.einsum(spec, x, w)``, for a weight ``w``.  A
+    weight held narrower than ``x`` (``train.step.at_product_width``) is
+    read as it is held: ``x`` is rounded to its type and the product
+    accumulates and returns in ``x``'s type, which is what one bfloat16
+    pass of the wider product computes.  At equal widths this is the
+    plain expression."""
+    if jnp.finfo(w.dtype).bits >= jnp.finfo(x.dtype).bits:
+        return x @ w if spec is None else jnp.einsum(spec, x, w)
+    out = x.dtype
+    spec = spec or "...d,df->...f"
+
+    def one_pass(x, w):
+        return jnp.einsum(spec, x, w, preferred_element_type=out)
+
+    def widened(x, w):
+        # the same values where the backend's dot lacks the narrow
+        # operands with a wide result (the CPU's, for batched products)
+        return jnp.einsum(spec, x.astype(out), w.astype(out),
+                          precision="highest")
+
+    return jax.lax.platform_dependent(x.astype(w.dtype), w, tpu=one_pass,
+                                      default=widened)
+
+
 # ----------------------------------------------------------------- MLP
 def swiglu(x, w_gate, w_up, w_down, shard=None):
-    h = jax.nn.silu(x @ w_gate) * (x @ w_up)
+    h = jax.nn.silu(product(x, w_gate)) * product(x, w_up)
     if shard is not None:
         h = shard(h, "batch", "seq", "ff")
-    return h @ w_down
+    return product(h, w_down)
 
 
 # ------------------------------------------------------- declarations
@@ -216,9 +242,9 @@ def mlp_decl(cfg) -> dict:
 def attn_qkv(params, x, positions, cfg):
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
-    q = x @ params["wq"]
-    k = x @ params["wk"]
-    v = x @ params["wv"]
+    q = product(x, params["wq"])
+    k = product(x, params["wk"])
+    v = product(x, params["wv"])
     if cfg.qkv_bias:
         q = q + params["bq"]
         k = k + params["bk"]
@@ -245,7 +271,7 @@ def attn_train(params, x, cfg, *, window=0, causal=True, shard=None,
     out = blockwise_attention(q, k, v, causal=causal, window=window,
                               softcap=cfg.attn_logit_softcap,
                               q_chunk=q_chunk, kv_chunk=kv_chunk)
-    return out.reshape(B, S, cfg.q_dim) @ params["wo"]
+    return product(out.reshape(B, S, cfg.q_dim), params["wo"])
 
 
 def _quantize_kv(t):
@@ -320,7 +346,7 @@ def attn_decode(params, x, cache, pos, cfg, *, window=0, shard=None,
         v_cache = shard(v_cache, "batch", kv_seq_ax, "kv_heads", "head_dim")
     out = decode_attention(q, k_cache, v_cache, pos, window=window,
                            softcap=cfg.attn_logit_softcap)
-    out = out.reshape(B, 1, cfg.q_dim) @ params["wo"]
+    out = product(out.reshape(B, 1, cfg.q_dim), params["wo"])
     return out, new_cache
 
 
@@ -331,8 +357,8 @@ def _mla_project(params, x, positions, cfg):
     c (B,S,kv_lora_rank), k_pe (B,S,rope)."""
     B, S, _ = x.shape
     H, nope = cfg.num_heads, cfg.qk_nope_head_dim
-    q = (x @ params["wq"]).reshape(B, S, H, -1)
-    kva = x @ params["wkva"]
+    q = product(x, params["wq"]).reshape(B, S, H, -1)
+    kva = product(x, params["wkva"])
     c = rmsnorm(kva[..., :cfg.kv_lora_rank], params["kva_norm"],
                 cfg.norm_eps)
     k_pe = rope(kva[..., None, cfg.kv_lora_rank:], positions,
@@ -355,8 +381,8 @@ def mla_train(params, x, cfg, *, q_chunk=512, kv_chunk=512):
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     q_nope, q_pe, c, k_pe = _mla_project(params, x, positions, cfg)
     w_uk, w_uv = _mla_up(params, cfg)
-    k_nope = jnp.einsum("bsc,chn->bshn", c, w_uk)
-    v = jnp.einsum("bsc,chv->bshv", c, w_uv)
+    k_nope = product(c, w_uk, "bsc,chn->bshn")
+    v = product(c, w_uv, "bsc,chv->bshv")
     H = cfg.num_heads
     q = jnp.concatenate([q_nope, q_pe], -1)
     k = jnp.concatenate(
@@ -364,7 +390,7 @@ def mla_train(params, x, cfg, *, q_chunk=512, kv_chunk=512):
         -1)
     out = blockwise_attention(q, k, v, causal=True, q_chunk=q_chunk,
                               kv_chunk=kv_chunk)
-    return out.reshape(B, S, H * cfg.v_head_dim) @ params["wo"]
+    return product(out.reshape(B, S, H * cfg.v_head_dim), params["wo"])
 
 
 def mla_decode(params, x, cache, pos, cfg, *, mask=None, layer=None):
@@ -395,11 +421,11 @@ def mla_decode(params, x, cache, pos, cfg, *, mask=None, layer=None):
         ckv = cache["ckv"].at[lead + (jnp.arange(B), slot)].set(
             c[:, 0], mode="drop")
     w_uk, w_uv = _mla_up(params, cfg)
-    q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0], w_uk)
+    q_lat = product(q_nope[:, 0], w_uk, "bhn,chn->bhc")
     o_lat, kpe = ops.mla_decode(
         q_lat, q_pe[:, 0], k_pe[:, 0], ckv, cache["kpe"], pos, slot,
         scale=(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5,
         layer=layer)
     new_cache = {"ckv": ckv, "kpe": kpe}
-    o = jnp.einsum("bhc,chv->bhv", o_lat, w_uv)
-    return o.reshape(B, 1, -1) @ params["wo"], new_cache
+    o = product(o_lat, w_uv, "bhc,chv->bhv")
+    return product(o.reshape(B, 1, -1), params["wo"]), new_cache

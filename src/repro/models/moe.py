@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.builder import Leaf
+from repro.models.layers import product
 
 
 def moe_decl(cfg) -> dict:
@@ -175,11 +176,11 @@ def grouped_mlp(buf, w_gate, w_up, w_down, shard=None):
     On TPU this is the ``moe_gemm`` Pallas kernel (B folded into the
     grid); the einsums below are its exact oracle and the GSPMD path
     used for dry-run lowering."""
-    h = jax.nn.silu(jnp.einsum("becd,edf->becf", buf, w_gate)) * \
-        jnp.einsum("becd,edf->becf", buf, w_up)
+    h = jax.nn.silu(product(buf, w_gate, "becd,edf->becf")) * \
+        product(buf, w_up, "becd,edf->becf")
     if shard is not None:
         h = shard(h, "batch", "experts", None, "moe_ff")
-    return jnp.einsum("becf,efd->becd", h, w_down)
+    return product(h, w_down, "becf,efd->becd")
 
 
 def moe_mlp(params, x, cfg, shard=None, trust=None, return_held=False):
@@ -201,7 +202,7 @@ def moe_mlp(params, x, cfg, shard=None, trust=None, return_held=False):
     E = cfg.resolved_padded_experts
     C = capacity_for(cfg, S)
 
-    logits = jnp.einsum("bsd,de->bse", x, params["router"])
+    logits = product(x, params["router"], "bsd,de->bse")
     weights, expert_id, position, keep, aux = route(
         logits, k, C, cfg.num_experts, scoring=cfg.router_scoring,
         bias=params.get("b_corr"), scale=cfg.routed_scaling_factor)
@@ -244,7 +245,8 @@ def moe_mlp(params, x, cfg, shard=None, trust=None, return_held=False):
 
     if cfg.num_shared_experts:
         sp = params["shared"]
-        y = y + (jax.nn.silu(x @ sp["w_gate"]) * (x @ sp["w_up"])) @ sp["w_down"]
+        h = jax.nn.silu(product(x, sp["w_gate"])) * product(x, sp["w_up"])
+        y = y + product(h, sp["w_down"])
     out = (y, aux * cfg.router_aux_weight)
     if return_held:
         out += (keep.sum(-1, dtype=jnp.int32),)

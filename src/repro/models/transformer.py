@@ -19,7 +19,7 @@ from repro.models.builder import Leaf, stack
 from repro.models.config import LayerSpec, ModelConfig
 from repro.models.layers import (attn_decl, attn_decode, attn_train,
                                  mla_decl, mla_decode, mla_train, mlp_decl,
-                                 rmsnorm, swiglu)
+                                 product, rmsnorm, swiglu)
 
 
 # ------------------------------------------------------------- decls
@@ -126,6 +126,26 @@ def cache_decl(cfg: ModelConfig, batch: int, cache_len: int) -> dict:
 
 # cache and parameter lists of layers outside the scanned stack
 UNSTACKED = ("leading", "remainder")
+
+# leaves read only as a matrix product's weight, by the group that holds
+# them (``shared``: a MoE layer's shared experts).  The embedding is a
+# row gather (and a tied head reads it too), and the norm scales,
+# biases and the router's selection bias ``b_corr`` act elementwise
+PRODUCT_WEIGHTS = {"attn": ("wq", "wk", "wv", "wo"),
+                   "mla": ("wq", "wkva", "wkvb", "wo"),
+                   "moe": ("router", "w_gate", "w_up", "w_down"),
+                   "shared": ("w_gate", "w_up", "w_down"),
+                   "mlp": ("w_gate", "w_up", "w_down")}
+
+
+def is_product_weight(path) -> bool:
+    """Whether the leaf at ``path`` (a key path of ``model_decl``'s tree,
+    as ``jax.tree_util`` gives it) is a matrix product's weight."""
+    names = [k.key for k in path if isinstance(k, jax.tree_util.DictKey)]
+    if names == ["lm_head"]:
+        return True
+    return (len(names) >= 2
+            and names[-1] in PRODUCT_WEIGHTS.get(names[-2], ()))
 
 
 # ------------------------------------------- block-granular KV paging
@@ -268,7 +288,7 @@ def forward_train(params, tokens, cfg: ModelConfig, *, shard=None,
         aux = aux + a
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = x @ head
+    logits = product(x, head)
     if shard is not None:
         logits = shard(logits, "batch", "seq", "vocab")
     return logits, aux
@@ -399,7 +419,7 @@ def forward_decode(params, caches, tokens, pos, cfg: ModelConfig, *,
         x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
         head = (params["embed"].T if cfg.tie_embeddings
                 else params["lm_head"])
-        logits = x @ head
+        logits = product(x, head)
     if cfg.expert_share:
         return (logits, new_caches,
                 sum(helds) if helds else jnp.zeros((), jnp.int32))

@@ -49,7 +49,8 @@ from repro.serve.scheduler import SlotScheduler, SlotState
 from repro.storage import ExpertCache, ExpertStore, StorageNetwork
 from repro.storage.kv import (KV_GENESIS, KVBlockStore, KVStorageConfig,
                               prefix_chain, prefix_cid)
-from repro.train.step import make_serve_chunk_step
+from repro.train.step import (at_product_width, make_serve_chunk_step,
+                              product_dtype)
 from repro.trust.audit import VerifierPool
 from repro.trust.commitments import MerkleTree, RoundCommitment, leaf_digest
 from repro.trust.da import DataAvailabilityAuditor
@@ -184,8 +185,19 @@ class ServingEngine:
         if cfg.is_encoder_decoder:
             raise NotImplementedError("engine drives decoder-only archs")
         self.cfg = cfg
-        self.params = params
         self.obs = obs if obs is not None else Observability()
+        # every matrix product's weight held at the width the product
+        # reads it (bfloat16 for one bfloat16 pass on a TPU), rounded
+        # once here rather than inside every call of the step
+        platform = (mesh.devices.flat[0].platform if mesh is not None
+                    else jax.default_backend())
+        self.params, narrowed = at_product_width(
+            params, product_dtype(cfg, platform))
+        flat = jax.tree_util.tree_flatten_with_path(self.params)[0]
+        self.obs.metrics.gauge("serve.product_weight_bytes").set(
+            sum(a.nbytes for path, a in flat if tfm.is_product_weight(path)))
+        self.obs.metrics.gauge("serve.product_weight_narrowed_bytes").set(
+            sum(a.nbytes for path, a in flat if path in narrowed))
         self.batch = batch_slots
         self.cache_len = cache_len
         self.prefill_chunk = max(1, int(prefill_chunk))

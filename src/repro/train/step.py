@@ -40,6 +40,39 @@ def at_precision(cfg: ModelConfig, fn):
     return at
 
 
+def product_dtype(cfg: ModelConfig, platform: str):
+    """The type at which ``cfg``'s matrix products read a weight on
+    ``platform`` (a JAX platform name): bfloat16 where its precision is
+    one bfloat16 pass (``default`` or ``bfloat16`` on a TPU), else None,
+    the weight as it is given (``high`` and ``highest`` read float32
+    through several passes; the CPU computes ``default`` in float32)."""
+    if platform == "tpu" and cfg.matmul_precision in ("default", "bfloat16"):
+        return jnp.bfloat16
+    return None
+
+
+def at_product_width(params, dtype):
+    """``params`` with every matrix product's weight
+    (``tfm.is_product_weight``) that is wider than ``dtype`` rounded to
+    it, once, in one compiled call; every other leaf is the caller's
+    own array, and the caller's tree is left as it was.  ``dtype`` None
+    keeps the tree.  Returns (tree, paths of the rounded leaves)."""
+    if dtype is None:
+        return params, []
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    bits = jnp.finfo(dtype).bits
+    narrow = [i for i, (path, a) in enumerate(flat)
+              if tfm.is_product_weight(path)
+              and jnp.finfo(a.dtype).bits > bits]
+    rounded = jax.jit(lambda xs: [x.astype(dtype) for x in xs])(
+        [flat[i][1] for i in narrow])
+    leaves = [a for _, a in flat]
+    for i, a in zip(narrow, rounded):
+        leaves[i] = a
+    return (jax.tree_util.tree_unflatten(treedef, leaves),
+            [flat[i][0] for i in narrow])
+
+
 def model_forward(params, batch, cfg: ModelConfig, shard=None, trust=None,
                   remat=True, unroll=False):
     """Dispatch on architecture family.  Returns (logits, aux, labels)."""

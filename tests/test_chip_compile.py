@@ -14,6 +14,11 @@ compiled at ``moonlight-16b-a3b``'s chip share: 32 slots, a cache of
 8,192 rows, 16 heads, latent 512, rotary key 64, one leading and 8
 stacked layers.
 
+Mixtral 8x7B's serve step (one block: hidden 4096, 32 query and 8 KV
+heads, 8 experts of 14,336, vocabulary 32,000; 32 slots, a cache of
+2,048 rows, 16 micro-steps a call) is compiled with the weights the
+serving engine holds on a TPU.
+
 Only one process at a time may load the TPU library, so the topology is
 described inside a module-scoped fixture (never at import), and the
 compiles happen in the test's own process.
@@ -21,6 +26,7 @@ compiles happen in the test's own process.
 import dataclasses
 import re
 
+import numpy as np
 import pytest
 
 import jax
@@ -183,3 +189,117 @@ def test_serve_step_reads_the_latent_cache_in_place_for_v5e(one_chip,
     temp = {k: c.memory_analysis().temp_size_in_bytes
             for k, c in compiled.items()}
     assert temp["pallas"] <= temp["ref"], temp
+
+
+# ------------------------------------------ product weights' held width
+MIXTRAL_SLOTS, MIXTRAL_CAP, CHUNK_WIDTH = 32, 2048, 16
+BIG = 1 << 20                  # elements: a weight, not a token's values
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]"
+                    r"(\{[^}]*\})? ([\w\-]+)\(([^)]*)\)")
+_BITS = {"f32": 32, "bf16": 16, "f16": 16}
+
+
+def _mixtral():
+    from repro.configs import get_config
+    return dataclasses.replace(
+        get_config("bmoe-paper"), num_layers=1, num_blocks=1, d_model=4096,
+        num_heads=32, num_kv_heads=8, head_dim=128, d_ff=14336,
+        moe_d_ff=14336, num_experts=8, num_experts_per_tok=2,
+        num_shared_experts=0, vocab_size=32000, rope_theta=1e6,
+        norm_eps=1e-5, tie_embeddings=False).validate()
+
+
+def _held(cfg, sharding):
+    """The abstract weights (float32 as given) and what the serving
+    engine's rule holds of them on a TPU."""
+    from repro.models import transformer as tfm
+    from repro.models.builder import abstract
+    from repro.train.step import at_product_width, product_dtype
+    given = abstract(tfm.model_decl(cfg), jnp.float32)
+    held = jax.eval_shape(
+        lambda p: at_product_width(p, product_dtype(cfg, "tpu"))[0], given)
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: _spec(a.shape, sharding, a.dtype), tree)
+    return place(given), place(held)
+
+
+def _serve_step(cfg, params, sharding, slots, cap):
+    from repro.models import transformer as tfm
+    from repro.models.builder import abstract
+    from repro.train.step import make_serve_chunk_step
+    caches = jax.tree_util.tree_map(
+        lambda a: _spec(a.shape, sharding, a.dtype),
+        abstract(tfm.cache_decl(cfg, slots, cap), jnp.float32))
+    batch = {"tokens": _spec((slots, CHUNK_WIDTH), sharding, jnp.int32),
+             **{k: _spec((slots,), sharding, jnp.int32)
+                for k in ("start", "pos", "lengths", "adv")}}
+    return jax.jit(make_serve_chunk_step(cfg)).lower(params, caches, batch)
+
+
+def _weight_moves(txt: str, shapes):
+    """Instructions that convert a float array shaped like a weight (its
+    dimensions of size one aside) of a million elements or more to a
+    narrower type, or copy one into the device's HBM (an on-chip copy,
+    layout ``S(1)``, is the loop's prefetch)."""
+    types, found = {}, []
+    for line in txt.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, dt, dims, layout, op, args = m.groups()
+        types[name] = (dt, tuple(int(d) for d in dims.split(",") if d))
+        if op not in ("convert", "copy") or not args.strip():
+            continue
+        src = types.get(args.split(",")[0].strip().lstrip("%"))
+        if src is None or src[0] not in _BITS or dt not in _BITS:
+            continue
+        core = tuple(d for d in src[1] if d != 1)
+        if core not in shapes or int(np.prod(core)) < BIG:
+            continue
+        narrower = _BITS[dt] < _BITS[src[0]]
+        if narrower or (op == "copy" and "S(1)" not in (layout or "")):
+            found.append(f"{name} {src} -> {dt}{layout or ''} {op}")
+    return found
+
+
+def test_serve_step_reads_held_weights_as_held_for_v5e(one_chip):
+    """Mixtral's serve step given the weights the engine holds on a TPU
+    (every product weight in bfloat16): no held weight is converted or
+    copied to HBM per call, every expert and head product reads its
+    bfloat16 weight, and the temporaries fall by about the bfloat16
+    weights against float32 weights, which the step converts each call."""
+    from repro.models import transformer as tfm
+    cfg = _mixtral()
+    given, held = _held(cfg, one_chip)
+    flat = jax.tree_util.tree_flatten_with_path(held)[0]
+    products = [a for path, a in flat if tfm.is_product_weight(path)]
+    assert {a.dtype for a in products} == {jnp.dtype(jnp.bfloat16)}
+    shapes = {tuple(d for d in a.shape if d != 1) for a in products}
+    compiled = {k: _serve_step(cfg, p, one_chip, MIXTRAL_SLOTS,
+                               MIXTRAL_CAP).compile()
+                for k, p in (("given", given), ("held", held))}
+    txt = compiled["held"].as_text()
+    assert _weight_moves(txt, shapes) == []
+    assert _weight_moves(compiled["given"].as_text(), shapes) != []
+    for ffn in ("14336", "32000"):
+        assert re.search(rf"bf16\[[\d,]*{ffn}[\d,]*\]\S* parameter\(", txt)
+    temp = {k: c.memory_analysis().temp_size_in_bytes
+            for k, c in compiled.items()}
+    narrow = sum(a.size * 2 for a in products)
+    assert temp["held"] <= temp["given"] - 0.9 * narrow, (temp, narrow)
+
+
+def test_moonlight_serve_step_reads_float32_weights_for_v5e(one_chip):
+    """At ``matmul_precision`` ``high`` the rule holds every weight as
+    given: Moonlight's step takes float32 weights on a TPU."""
+    from repro.configs import get_config
+    cfg = dataclasses.replace(
+        get_config("moonlight-16b-a3b"), num_layers=STACKED + 1,
+        num_blocks=STACKED, held_experts=tuple(range(8)),
+        vocab_size=20480, matmul_precision="high").validate()
+    given, held = _held(cfg, one_chip)
+    assert held == given
+    txt = _serve_step(cfg, held, one_chip, SLOTS, CAP).as_text()
+    main = next(line for line in txt.splitlines()
+                if "func.func public @main" in line)
+    assert "bf16" not in main and "xf32>" in main
